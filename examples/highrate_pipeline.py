@@ -1,7 +1,8 @@
 """High-rate ingestion demo: derived capacity + columnar feed + pipelining.
 
 The round-5 throughput surface, end to end in one script (run
-``CEP_PLATFORM=cpu python examples/highrate_pipeline.py``):
+``python examples/highrate_pipeline.py``; ``JAX_PLATFORMS=cpu`` runs it
+on the CPU):
 
 1. **Capacity is derived, not guessed** — ``engine.autosize`` probes a
    sample of the real traffic and returns an :class:`EngineConfig` whose
@@ -25,11 +26,6 @@ import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("CEP_PLATFORM"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["CEP_PLATFORM"])
 
 import numpy as np
 import jax.numpy as jnp
@@ -116,4 +112,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from kafkastreams_cep_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

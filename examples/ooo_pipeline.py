@@ -3,7 +3,8 @@
 Real streams are disordered in event time and occasionally poisoned per
 record; the reference absorbs both at the Kafka layer.  This script runs
 the TPU runtime's front-door analog end to end
-(``CEP_PLATFORM=cpu python examples/ooo_pipeline.py``):
+(``python examples/ooo_pipeline.py``; ``JAX_PLATFORMS=cpu`` runs it on the
+CPU):
 
 1. a stock stream whose arrival order is shuffled with bounded timestamp
    skew, fed through the watermark reorder buffer
@@ -18,16 +19,10 @@ the TPU runtime's front-door analog end to end
    anything) the guard had to shed.
 """
 
-import os
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("CEP_PLATFORM"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["CEP_PLATFORM"])
 
 import numpy as np
 
@@ -140,4 +135,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from kafkastreams_cep_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

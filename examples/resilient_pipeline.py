@@ -2,7 +2,8 @@
 crash recovery.
 
 Everything the reference delegates to Kafka Streams, end to end in one
-script (run ``CEP_PLATFORM=cpu python examples/resilient_pipeline.py``):
+script (run ``python examples/resilient_pipeline.py``; ``JAX_PLATFORMS=cpu``
+runs it on the CPU):
 
 1. two queries over one stock stream (the NFA-bank shape — one processor
    per query, like wiring two ``CEPProcessor`` instances onto one topic);
@@ -19,11 +20,6 @@ import tempfile
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("CEP_PLATFORM"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["CEP_PLATFORM"])
 
 import numpy as np
 
@@ -128,4 +124,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from kafkastreams_cep_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -121,10 +121,10 @@ class EngineConfig:
     # overflow tier when the hot tier fills), and the walk passes resolve
     # each hop against the hot rows first, touching the overflow rows only
     # on a miss — in the Pallas kernels the common hop pays an E_hot-sized
-    # reduce instead of an E-sized one (PROFILE_r05.md finding 2).  Capacity
-    # semantics are unchanged: every drop counter is bit-identical to the
-    # single-tier engine, and matches/slab contents agree modulo which slot
-    # (tier) an entry occupies.  Must be a multiple of 8 (TPU sublane tile)
+    # reduce instead of an E-sized one (PERF.md, walk-pass cost model).
+    # Capacity semantics are unchanged: every drop counter is bit-identical
+    # to the single-tier engine, and matches/slab contents agree modulo which
+    # slot (tier) an entry occupies.  Must be a multiple of 8 (TPU sublane tile)
     # strictly below slab_entries.  Residency telemetry rides the
     # slab_hot_hits / slab_hot_misses / slab_overflow_walks /
     # slab_demotions counters (HOT_COUNTER_NAMES).
@@ -160,19 +160,19 @@ class EngineConfig:
     # the same per-entry op order (see ops/slab.py) and is ~2 orders of
     # magnitude faster on TPU; this switch exists for differential testing.
     sequential_slab: bool = False
-    # Lazy match extraction (PROFILE_r06 "next leverage" item 1): when True,
-    # a run reaching the final stage no longer dispatches its W-hop
-    # extraction walk inside the per-step walk pass — the dominant walker
-    # class and the main source of two-tier hot misses on match-dense
-    # traces (PROFILE_r05 finding 2).  Instead the step emits a fixed-width
-    # *handle* (root stage, root offset, Dewey version, completion step +
-    # run row + timestamp) into a per-lane handle ring and *pins* the
-    # referenced chain (refcount +1 at the root, so no removal walk can
-    # delete it before drain; the maintenance sweep additionally roots
-    # pending handles).  Materialization moves to the batched drain pass
-    # (``TPUMatcher.drain`` / ``BatchMatcher.drain``) that unpins and walks
-    # all pending handles together, off the per-step critical path.  The
-    # drained match set is identical to the eager engine's
+    # Lazy match extraction (PROFILE_r06 "next leverage" item 1): when True, a
+    # run reaching the final stage no longer dispatches its W-hop extraction
+    # walk inside the per-step walk pass — the dominant walker class and the
+    # main source of two-tier hot misses on match-dense traces (PERF.md,
+    # walk-pass cost model).  Instead the step emits a fixed-width *handle*
+    # (root stage, root offset, Dewey version, completion step + run row +
+    # timestamp) into a per-lane handle ring and *pins* the referenced chain
+    # (refcount +1 at the root, so no removal walk can delete it before drain;
+    # the maintenance sweep additionally roots pending handles).
+    # Materialization moves to the batched drain pass (``TPUMatcher.drain`` /
+    # ``BatchMatcher.drain``) that unpins and walks all pending handles
+    # together, off the per-step critical path.  The drained match set is
+    # identical to the eager engine's
     # (tests/test_lazy_extraction.py); eager mode remains the differential
     # oracle.
     lazy_extraction: bool = False
@@ -353,13 +353,13 @@ HOT_COUNTER_NAMES = (
     "slab_demotions",
 )
 
-# Walk-cost telemetry (PROFILE_r05/r06: the walk pass is compute-bound on
-# per-hop reduces x lockstep trip counts) — like HOT_COUNTER_NAMES these are
-# NOT loss indicators and live outside COUNTER_NAMES; they make the
-# reduce-width perf model measurable on CPU CI.  ``extract_hops`` counts
-# eager in-step extraction walk hops; ``drain_hops`` the deferred drain
-# pass's (lazy_extraction); ``walk_hops`` everything else (branch refcount
-# walks, dead-run removals).
+# Walk-cost telemetry (PERF.md walk-pass cost model, PROFILE_r06: the walk pass
+# is compute-bound on per-hop reduces x lockstep trip counts) — like
+# HOT_COUNTER_NAMES these are NOT loss indicators and live outside
+# COUNTER_NAMES; they make the reduce-width perf model measurable on CPU CI.
+# ``extract_hops`` counts eager in-step extraction walk hops; ``drain_hops``
+# the deferred drain pass's (lazy_extraction); ``walk_hops`` everything else
+# (branch refcount walks, dead-run removals).
 WALK_COUNTER_NAMES = (
     "walk_hops",
     "extract_hops",

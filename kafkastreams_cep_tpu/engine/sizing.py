@@ -204,12 +204,12 @@ def suggest(tables, report: ProbeReport, margin: float = 1.5) -> EngineConfig:
 def suggest_hot_entries(slab_entries: int, max_alive_runs: int) -> int:
     """E_hot for a derived ``slab_entries``.
 
-    The hot tier is a perf knob, not a capacity knob (drops are identical
-    at any E_hot — ops/slab.py "Two-tier layout"), so sizing targets the
-    walk access pattern: walks start at run pointer events and the current
-    event, so the per-step *fresh* working set is bounded by the live run
-    count, and PROFILE_r05's E-sweep puts the sweet spot for the hot
-    window at ~16-24 rows.  Below E=32 a two-tier split buys nothing (the
+    The hot tier is a perf knob, not a capacity knob (drops are identical at
+    any E_hot — ops/slab.py "Two-tier layout"), so sizing targets the walk
+    access pattern: walks start at run pointer events and the current event, so
+    the per-step *fresh* working set is bounded by the live run count, and the
+    round-5 E-sweep (PERF.md, walk-pass cost model) puts the sweet spot for the
+    hot window at ~16-24 rows.  Below E=32 a two-tier split buys nothing (the
     full reduce is already hot-sized) and 0 keeps the legacy single tier.
     """
     if slab_entries < 32:

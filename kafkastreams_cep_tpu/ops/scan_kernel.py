@@ -57,13 +57,6 @@ logger = get_logger("ops.scan_kernel")
 
 LANE_BLOCK = 128
 
-# jax renamed TPUCompilerParams -> CompilerParams across the versions this
-# engine runs on (laptop CI pins an older jaxlib than the TPU hosts).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
-
 def _cumsum0(x):
     """Inclusive prefix sum along axis 0 via log-shift adds — Mosaic has
     no cumsum lowering; log2(N) shifted adds of the [N, L] plane do."""
@@ -1598,7 +1591,7 @@ def build_scan(tables, config: EngineConfig, promotion=None):
             in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shapes,
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=110 * 1024 * 1024,
                 dimension_semantics=("parallel", "arbitrary"),
             ),

@@ -84,8 +84,9 @@ class SlabState(NamedTuple):
     demotions: jnp.ndarray  # scalar int32 — hot -> overflow entry moves
     # --- walk-cost telemetry (never loss indicators): every active hop of
     #     every walker is classified exactly once by walker class, so the
-    #     reduce-width perf model (PROFILE_r05/r06: per-hop masked reduces x
-    #     lockstep trip counts) is measurable on CPU CI without a chip.
+    #     reduce-width perf model (PERF.md walk-pass cost model, PROFILE_r06:
+    #     per-hop masked reduces x lockstep trip counts) is measurable on CPU
+    #     CI without a chip.
     walk_hops: jnp.ndarray  # scalar int32 — branch/dead-removal walker hops
     extract_hops: jnp.ndarray  # scalar int32 — eager in-step extraction hops
     drain_hops: jnp.ndarray  # scalar int32 — deferred drain-pass hops (lazy)
@@ -162,7 +163,7 @@ def _alloc(slab: SlabState):
 # reduce runs over the hot rows only and the overflow rows are touched
 # under a block-level ``pl.when`` that skips entirely when every lane of
 # the block resolved hot — the E-linear hop cost drops to E_hot-linear on
-# the common path (PROFILE_r05.md finding 2, redesign candidate 1).
+# the common path (PERF.md, walk-pass cost model).
 # ---------------------------------------------------------------------------
 
 

@@ -41,13 +41,6 @@ from kafkastreams_cep_tpu.ops.slab import SlabState
 
 LANE_BLOCK = 128
 
-# jax renamed TPUCompilerParams -> CompilerParams across the versions this
-# engine runs on (laptop CI pins an older jaxlib than the TPU hosts).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
-
 def _cumsum0(x):
     """Inclusive prefix sum along axis 0 via log-shift adds — Mosaic has
     no cumsum lowering; log2(N) shifted adds of the [N, L] plane do."""
@@ -921,7 +914,7 @@ def walk_pass_kernel(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         scratch_shapes=scratch_shapes,

@@ -352,9 +352,9 @@ class BatchMatcher:
         # Opt-in (CEP_SCAN_KERNEL=1, or =interpret for CPU testing):
         # differential parity is pinned by tests/test_scan_kernel.py, and
         # measured throughput is at parity with the per-step walk kernel
-        # on the headline trace (see PROFILE_r05.md — both are bound by
-        # the same lockstep walk-pass vector work, not launch or HBM
-        # overheads), so the per-step path stays the default.
+        # on the headline trace (PERF.md, walk-pass cost model — both are
+        # bound by the same lockstep walk-pass vector work, not launch or
+        # HBM overheads), so the per-step path stays the default.
         self.uses_scan_kernel = False
         scan_mode = os.environ.get("CEP_SCAN_KERNEL", "0")
         if scan_mode in ("1", "interpret"):

@@ -89,10 +89,8 @@ class TimeShardedStencil:
             P(None, axis),
         )
         spec_out = (P(None, axis), P(None, axis, None))
-        from kafkastreams_cep_tpu.parallel.sharding import _shard_map
-
         self._match = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 local,
                 mesh=mesh,
                 in_specs=spec_in,

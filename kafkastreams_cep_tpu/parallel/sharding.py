@@ -83,19 +83,6 @@ def surviving_mesh(mesh: Mesh, dead, num_lanes: int) -> Optional[Mesh]:
     return key_mesh(survivors[:m], axis=mesh.axis_names[0])
 
 
-def _shard_map(*args, **kwargs):
-    """``jax.shard_map`` with fallback to the pre-0.5 experimental home
-    (the engine runs on older jaxlib in CI than on the TPU hosts).
-    ``check_vma`` was spelled ``check_rep`` there."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return fn(*args, **kwargs)
-
-
 def key_mesh(devices: Optional[Sequence] = None, axis: str = "keys") -> Mesh:
     """A 1-D mesh over ``devices`` (default: all) sharding the key axis.
 
@@ -183,7 +170,7 @@ class ShardedMatcher:
         # check_vma off: constants born inside fori_loop carries are
         # device-invariant and trip the varying-axes check; the hot path has
         # no collectives, so the replication analysis buys nothing here.
-        shard = lambda f, out_specs: _shard_map(
+        shard = lambda f, out_specs: jax.shard_map(
             f, mesh=mesh, in_specs=spec, out_specs=out_specs, check_vma=False
         )
         self.step = jax.jit(shard(local_step, spec))
@@ -263,7 +250,7 @@ class ShardedMatcher:
         local = jax.vmap(self.matcher._drain_fn)
         spec = P(self.axis)
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 local, mesh=self.mesh, in_specs=spec,
                 out_specs=(spec, spec), check_vma=False,
             )
@@ -285,7 +272,7 @@ class ShardedMatcher:
             )
 
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 local, mesh=self.mesh, in_specs=spec, out_specs=P(),
                 check_vma=False,
             )
@@ -381,7 +368,7 @@ class ShardedMatcher:
 
         spec = P(self.axis)
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 local, mesh=self.mesh, in_specs=spec, out_specs=spec,
                 check_vma=False,
             )

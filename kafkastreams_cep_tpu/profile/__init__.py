@@ -29,8 +29,8 @@ Subcommands
                  for the compiled scan, and (``--trace-dir``) an
                  optional ``jax.profiler`` trace capture.
 
-Every subcommand accepts ``--k/--t/--reps`` size knobs and ``--platform``
-(e.g. ``cpu``) so the tier-1 smoke test can drive tiny shapes on CI.
+Every subcommand accepts ``--k/--t/--reps`` size knobs, so the tier-1
+smoke test can drive tiny shapes on CI (``JAX_PLATFORMS=cpu``).
 """
 
 from __future__ import annotations
@@ -45,26 +45,6 @@ from typing import Any, Dict, List, Optional
 
 def _log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
-
-
-def _setup_jax(platform: Optional[str]) -> None:
-    import jax
-
-    if platform:
-        jax.config.update("jax_platforms", platform)
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.environ.get(
-            "CEP_BENCH_CACHE_DIR",
-            os.path.join(
-                os.environ.get("XDG_CACHE_HOME")
-                or os.path.join(os.path.expanduser("~"), ".cache"),
-                "cep_tpu_bench_cache",
-            ),
-        ),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 def _stock_pattern():
@@ -439,7 +419,10 @@ def run_ablate(args) -> Dict[str, Any]:
         return {"profile": "ablate-variant", "variant": args.variant,
                 "best_s": best}
     # Each variant in its own process (four matchers + executables do not
-    # share HBM on a real chip; also isolates the monkeypatch).
+    # share HBM on a real chip; also isolates the monkeypatch).  The
+    # children run one at a time, and this parent never initializes a JAX
+    # backend (only config updates and imports reach it), so each child
+    # gets the chip to itself.
     import subprocess
 
     results: Dict[str, float] = {}
@@ -449,8 +432,6 @@ def run_ablate(args) -> Dict[str, Any]:
             "--variant", v, "--k", str(K), "--t", str(T),
             "--reps", str(args.reps),
         ]
-        if args.platform:
-            cmd += ["--platform", args.platform]
         out = subprocess.run(cmd, capture_output=True, text=True)
         for line in out.stderr.splitlines():
             if "WARNING" not in line:
@@ -709,7 +690,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         sp.add_argument("--t", type=int, default=int(
             os.environ.get("PROF_T", "32")))
         sp.add_argument("--reps", type=int, default=2)
-        sp.add_argument("--platform", default=os.environ.get("CEP_PLATFORM"))
         sp.add_argument("--seed", type=int, default=42)
 
     common(sub.add_parser("step"), "512,4096,16384")
@@ -739,7 +719,9 @@ def main(argv: Optional[List[str]] = None) -> int:
             args.k = int(str(args.k).split(",")[0])
         except ValueError:
             p.error(f"--k must be an integer for {args.cmd}")
-    _setup_jax(args.platform)
+    from kafkastreams_cep_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     out = {
         "step": run_step,
         "phases": run_phases,

@@ -21,7 +21,7 @@ The TPU analog splits the same contract in two:
   processor lands in exactly the pre-failure state.
 
 Failure *detection* covers what a lost Kafka Streams task would surface:
-any exception out of the device dispatch (device reset, OOM, tunnel loss)
+any exception out of the device dispatch (device reset, OOM, lost host)
 triggers recovery, and :meth:`Supervisor.health` exposes the engine's
 overflow counters plus state-validity probes (NaN fold state, negative
 refcounts) as a typed report — the counters exist precisely because
@@ -287,7 +287,7 @@ class Supervisor:
         self.max_retries = int(max_retries)
         # Exponential retry backoff with deterministic jitter: a device
         # fault that survives the instant retry is usually environmental
-        # (reset storm, tunnel flap), and hammering it back-to-back turns
+        # (reset storm, flapping link), and hammering it back-to-back turns
         # one fault into a fault train.  Jitter derives from (seq,
         # attempt) so a given retry always waits the same time —
         # reproducible chaos runs.  Tests patch ``self._sleep``.

@@ -143,16 +143,19 @@ def annotate(name: str) -> Iterator[None]:
 
 
 def device_memory_stats() -> Dict[str, int]:
-    """HBM usage of the first device (empty dict when the backend doesn't
-    report) — sizing aid for lane-count / slab-shape capacity planning."""
+    """HBM usage of the fullest local device: each byte stat is the
+    largest over every local device, so a mesh whose state sits on one
+    chip is visible (empty dict when the backend doesn't report) — sizing
+    aid for lane-count / slab-shape capacity planning."""
     import jax
 
-    try:
-        stats = jax.local_devices()[0].memory_stats() or {}
-    except Exception:
-        return {}
-    return {
-        k: int(v)
-        for k, v in stats.items()
-        if isinstance(v, (int, float)) and "bytes" in k
-    }
+    out: Dict[str, int] = {}
+    for dev in jax.local_devices():
+        try:
+            stats = dev.memory_stats() or {}
+        except Exception:
+            continue
+        for k, v in stats.items():
+            if isinstance(v, (int, float)) and "bytes" in k:
+                out[k] = max(out.get(k, 0), int(v))
+    return out

@@ -107,8 +107,7 @@ def test_profiler_cli_selectivity_smoke():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "kafkastreams_cep_tpu.profile",
-         "selectivity", "--k", "8", "--t", "16", "--reps", "1",
-         "--platform", "cpu"],
+         "selectivity", "--k", "8", "--t", "16", "--reps", "1"],
         capture_output=True, text=True, cwd=_ROOT, env=env, timeout=600,
     )
     assert out.returncode == 0, out.stderr[-2000:]

@@ -1,6 +1,6 @@
 """Tier-1 guard: every bench timing path forces materialization.
 
-PROFILE_r05 finding 1: JAX dispatch is asynchronous, so a
+JAX dispatch is asynchronous, so a
 ``perf_counter`` span that never forces its outputs measures enqueue
 time, not device time — lazy outputs once made ``block_until_ready``-free
 timings physically impossible to trust, and a future edit could

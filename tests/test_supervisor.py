@@ -296,7 +296,7 @@ def test_plain_valueerror_from_device_triggers_recovery(tmp_path):
         try:
             return hook(state, events)
         except RuntimeError:
-            raise ValueError("INTERNAL: device tunnel dropped")
+            raise ValueError("INTERNAL: device connection dropped")
 
     sup.processor.batch.scan = value_error_scan
     out = sup.process([Record("k", sc.B, 2), Record("k", sc.C, 3)])
