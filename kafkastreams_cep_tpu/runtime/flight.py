@@ -58,6 +58,9 @@ _SECONDS_KEYS = (
     "device_seconds",
     "decode_seconds",
     "gc_seconds",
+    "decode_wait_seconds",
+    "decode_build_seconds",
+    "gc_pull_seconds",
 )
 
 

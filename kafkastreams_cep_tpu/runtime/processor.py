@@ -257,7 +257,8 @@ class CEPProcessor:
         self.metrics = Metrics()
         # Telemetry (utils/telemetry.py): an optional span sink — every
         # process() call emits one "batch" span with nested phase spans
-        # (pack -> dispatch -> device -> decode -> gc); None costs one
+        # (pack -> dispatch -> device -> decode -> gc; decode holds
+        # decode_wait and decode_build, gc holds gc_pull); None costs one
         # attribute check per phase.  ``name`` labels this processor in
         # per-pattern attribution (bank members pass their query name).
         self.trace = trace_sink
@@ -1065,8 +1066,7 @@ class CEPProcessor:
                     lat, (not self.lazy) or drain_out is not None
                 )
         if gc_due:
-            with self._phase("gc"):
-                self._gc_events()
+            self._gc_events()
         self.metrics.matches_out += len(matches)
         self._flight_tick()
         return matches
@@ -1163,10 +1163,11 @@ class CEPProcessor:
             from kafkastreams_cep_tpu.ops.decode import compact_drained
 
             K, HB = dout.count.shape
-            c_stage, c_off, c_count, c_seq, c_row, c_k, c_n, _ovf = (
-                compact_drained(dout, self.decode_budget)
-            )
-            n = int(c_n)
+            with self._phase("decode_wait"):
+                c_stage, c_off, c_count, c_seq, c_row, c_k, c_n, _ovf = (
+                    compact_drained(dout, self.decode_budget)
+                )
+                n = int(c_n)
             if n <= min(self.decode_budget, K * HB):
                 if n == 0:
                     return []
@@ -1229,12 +1230,15 @@ class CEPProcessor:
             from kafkastreams_cep_tpu.ops.decode import compact_matches
 
             K, T, R = out.count.shape
-            c_stage, c_off, c_count, c_k, c_t, c_r, c_n, _overflow = (
-                compact_matches(out, self.decode_budget)
-            )
-            # One scalar round-trip; overflow is host-derivable from it
-            # (an extra device_get would be a second host sync).
-            n = int(c_n)
+            # The device wait inside decode: whatever is still queued
+            # ahead of the compaction (the sweep) plus the compaction.
+            with self._phase("decode_wait"):
+                c_stage, c_off, c_count, c_k, c_t, c_r, c_n, _overflow = (
+                    compact_matches(out, self.decode_budget)
+                )
+                # One scalar round-trip; overflow is host-derivable from
+                # it (an extra device_get would be a second host sync).
+                n = int(c_n)
             if n <= min(self.decode_budget, K * T * R):
                 if n == 0:
                     return []
@@ -1277,15 +1281,16 @@ class CEPProcessor:
         """Already-ordered hit rows -> (key, Sequence) objects."""
         names = self.batch.names
         matches: List[Tuple[Hashable, Sequence]] = []
-        for i in range(ks.size):
-            k = int(ks[i])
-            seq = Sequence()
-            for w in range(int(cnts[i])):
-                seq.add(
-                    names[int(stages[i, w])],
-                    self._event_at(k, int(offs[i, w])),
-                )
-            matches.append((self._key_of[k], seq))
+        with self._phase("decode_build"):
+            for i in range(ks.size):
+                k = int(ks[i])
+                seq = Sequence()
+                for w in range(int(cnts[i])):
+                    seq.add(
+                        names[int(stages[i, w])],
+                        self._event_at(k, int(offs[i, w])),
+                    )
+                matches.append((self._key_of[k], seq))
         return matches
 
     def _event_at(self, lane: int, off: int) -> Event:
@@ -1320,34 +1325,39 @@ class CEPProcessor:
         buffer (``KVSharedVersionedBuffer.java:147-171``); the host mirror
         only needs events still present in a lane's slab or pointed at by a
         live run, so everything else is released here after each batch.
+        Timed as the ``gc`` phase wherever it runs (checkpoints call it
+        too), with the liveness transfer as its ``gc_pull`` child.
         """
-        # Tiered processors wrap the engine state (engine/tiered.py);
-        # liveness lives in the engine half either way.
-        eng = getattr(self.state, "engine", self.state)
-        slab_stage = np.asarray(jax.device_get(eng.slab.stage))  # [K, E]
-        slab_off = np.asarray(jax.device_get(eng.slab.off))
-        run_alive = np.asarray(jax.device_get(eng.alive))  # [K, R]
-        run_off = np.asarray(jax.device_get(eng.event_off))
-        for k in range(self.num_lanes):
-            live = set(slab_off[k][slab_stage[k] >= 0].tolist())
-            live.update(run_off[k][run_alive[k]].tolist())
-            # Live rows still sitting in lazy column batches materialize
-            # now (the batches are dropped below); dead rows never do.
-            for start, cnt, abs_ts, leaves in self._col_batches:
-                s = int(start[k])
-                if s < 0:
-                    continue
-                hi = s + int(cnt[k])
-                for o in live:
-                    if s <= o < hi and o not in self._events[k]:
-                        self._events[k][o] = self._materialize(
-                            k, o, s, abs_ts, leaves
-                        )
-            store = self._events[k]
-            dead = [o for o in store if o not in live]
-            for o in dead:
-                del store[o]
-        self._col_batches.clear()
+        with self._phase("gc"):
+            # Tiered processors wrap the engine state (engine/tiered.py);
+            # liveness lives in the engine half either way.
+            eng = getattr(self.state, "engine", self.state)
+            with self._phase("gc_pull"):
+                slab_stage = np.asarray(jax.device_get(eng.slab.stage))  # [K, E]
+                slab_off = np.asarray(jax.device_get(eng.slab.off))
+                run_alive = np.asarray(jax.device_get(eng.alive))  # [K, R]
+                run_off = np.asarray(jax.device_get(eng.event_off))
+            for k in range(self.num_lanes):
+                live = set(slab_off[k][slab_stage[k] >= 0].tolist())
+                live.update(run_off[k][run_alive[k]].tolist())
+                # Live rows still sitting in lazy column batches
+                # materialize now (the batches are dropped below); dead
+                # rows never do.
+                for start, cnt, abs_ts, leaves in self._col_batches:
+                    s = int(start[k])
+                    if s < 0:
+                        continue
+                    hi = s + int(cnt[k])
+                    for o in live:
+                        if s <= o < hi and o not in self._events[k]:
+                            self._events[k][o] = self._materialize(
+                                k, o, s, abs_ts, leaves
+                            )
+                store = self._events[k]
+                dead = [o for o in store if o not in live]
+                for o in dead:
+                    del store[o]
+            self._col_batches.clear()
 
     def lane_shards(self) -> Optional[List[int]]:
         """The live lane→shard assignment (contiguous blocks over the

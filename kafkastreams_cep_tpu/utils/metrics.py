@@ -35,7 +35,11 @@ COUNTER_ATTRS = (
 )
 
 #: Wall-time accumulators; each also feeds the phase histogram of the same
-#: stem ("device_seconds" -> phases["device"]).
+#: stem ("device_seconds" -> phases["device"]).  The last three are
+#: sub-phases, timed inside their parent: ``decode_wait`` (the device wait
+#: for the match compaction) and ``decode_build`` (Sequence/Event
+#: construction) inside ``decode``, ``gc_pull`` (the liveness transfer)
+#: inside ``gc``; a parent's self time is its seconds minus its children's.
 SECONDS_ATTRS = (
     "device_seconds",
     "decode_seconds",
@@ -43,12 +47,16 @@ SECONDS_ATTRS = (
     "dispatch_seconds",
     "drain_seconds",
     "gc_seconds",
+    "decode_wait_seconds",
+    "decode_build_seconds",
+    "gc_pull_seconds",
 )
 
 #: The batch phases every processor pre-registers, so snapshots of runs
 #: that never hit a phase (e.g. gc off, eager extraction) still carry
 #: identical key sets.
-PHASE_NAMES = ("pack", "dispatch", "drain", "device", "decode", "gc")
+PHASE_NAMES = ("pack", "dispatch", "drain", "device", "decode", "gc",
+               "decode_wait", "decode_build", "gc_pull")
 
 
 def _counter_property(name: str) -> property:

@@ -335,12 +335,18 @@ class TraceSink:
     call sequence); the active-span stack supplies ``parent_id``, so
     phases opened inside a batch span nest under it without any explicit
     plumbing.  Subclasses implement :meth:`write`.
+
+    ``annotate=True`` also opens a ``jax.profiler.TraceAnnotation`` of the
+    span's name around every span, so the spans land on the clock of a
+    profiler trace captured meanwhile (``utils.metrics.profile``) next to
+    the device's programs; the records emitted are the same either way.
     """
 
-    def __init__(self):
+    def __init__(self, annotate: bool = False):
         self._ids = itertools.count(1)
         self._stack: List[int] = []
         self._lock = threading.Lock()
+        self.annotate = annotate
 
     # subclass hook
     def write(self, event: Dict[str, Any]) -> None:
@@ -376,11 +382,18 @@ class TraceSink:
             parent = self._stack[-1] if self._stack else None
             self._stack.append(sid)
         extra: Dict[str, Any] = {}
+        if self.annotate:
+            import jax
+
+            region = jax.profiler.TraceAnnotation(name)
+        else:
+            region = contextlib.nullcontext()
         wall = time.time()
         t0 = time.perf_counter()
         err: Optional[str] = None
         try:
-            yield extra
+            with region:
+                yield extra
         except BaseException as e:
             err = f"{type(e).__name__}: {e}"
             raise
@@ -407,8 +420,8 @@ class TraceSink:
 class InMemoryTraceSink(TraceSink):
     """Collects events in ``self.events`` — tests and ad-hoc inspection."""
 
-    def __init__(self):
-        super().__init__()
+    def __init__(self, annotate: bool = False):
+        super().__init__(annotate)
         self.events: List[Dict[str, Any]] = []
 
     def write(self, event: Dict[str, Any]) -> None:
@@ -447,8 +460,9 @@ class JsonlTraceSink(TraceSink):
         target,
         max_bytes: Optional[int] = None,
         max_age_s: Optional[float] = None,
+        annotate: bool = False,
     ):
-        super().__init__()
+        super().__init__(annotate)
         self.max_bytes = max_bytes
         self.max_age_s = max_age_s
         self.rollovers = 0

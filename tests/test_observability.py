@@ -72,6 +72,18 @@ def test_flight_ring_is_bounded_and_dump_schema(tmp_path):
     assert read_dump(fr.dump("demand"))["header"]["records"] == 3
 
 
+def test_flight_record_splits_gc_into_pull_and_scan():
+    """A GC batch's record carries the liveness pull beside the whole GC,
+    so a GC stall's dump shows transfer against per-lane scan."""
+    fr = FlightRecorder(capacity=4)
+    proc = CEPProcessor(stock_demo.stock_pattern(), 4, CFG, epoch=0,
+                        flight=fr, gc_events_interval=1)
+    proc.process(stock_records(16))
+    ph = fr.records[-1]["phase_seconds"]
+    assert 0 < ph["gc_pull"] <= ph["gc"]
+    assert ph["decode_wait"] + ph.get("decode_build", 0.0) <= ph["decode"]
+
+
 def test_flight_observe_without_path_returns_records():
     fr = FlightRecorder(capacity=8)
     proc = CEPProcessor(stock_demo.stock_pattern(), 2, CFG, epoch=0,

@@ -229,6 +229,70 @@ def test_processor_batch_and_phase_spans():
                     "phase.decode"]
 
 
+def _children(sink, parent):
+    return [
+        s["name"] for s in sink.spans() if s["parent_id"] == parent["span_id"]
+    ]
+
+
+def _columns_run(sink, **kw):
+    recs = stock_records()
+    proc = CEPProcessor(
+        stock_demo.stock_pattern(), 1, stock_cfg(), trace_sink=sink, **kw
+    )
+    matches = proc.process_columns(
+        np.zeros(len(recs), np.int64),
+        {f: np.asarray([r.value[f] for r in recs]) for f in ("price", "volume")},
+        np.asarray([r.timestamp for r in recs]),
+    )
+    assert len(matches) == 4
+    return proc
+
+
+@pytest.mark.parametrize("gc_events_interval", [8, 1])
+def test_processor_sub_phase_spans_nest_under_their_phase(gc_events_interval):
+    """decode_wait and decode_build are children of decode, gc_pull of gc;
+    the batch's own children stay the phases, and no sub-phase's seconds
+    exceed its parent's."""
+    sink = InMemoryTraceSink()
+    proc = _columns_run(sink, gc_events_interval=gc_events_interval)
+    batch = sink.spans("batch")[0]
+    gc = gc_events_interval == 1
+    assert _children(sink, batch) == [
+        "phase.pack", "phase.dispatch", "phase.device", "phase.decode"
+    ] + (["phase.gc"] if gc else [])
+    decode = sink.spans("phase.decode")[0]
+    assert _children(sink, decode) == ["phase.decode_wait",
+                                       "phase.decode_build"]
+    if gc:
+        assert _children(sink, sink.spans("phase.gc")[0]) == ["phase.gc_pull"]
+    else:
+        assert not sink.spans("phase.gc_pull")
+    m = proc.metrics
+    assert m.decode_wait_seconds > 0 and m.decode_build_seconds > 0
+    assert m.decode_wait_seconds + m.decode_build_seconds <= m.decode_seconds
+    assert m.gc_pull_seconds <= m.gc_seconds
+    assert (m.gc_pull_seconds > 0) == gc
+    phases = m.phases()
+    assert phases["decode_build"]["count"] == 1
+    assert phases["gc_pull"]["count"] == int(gc)
+
+
+def test_annotated_sink_emits_the_same_span_records():
+    """``annotate=True`` adds profiler annotations and changes no record."""
+
+    def records(sink):
+        _columns_run(sink, gc_events_interval=1)
+        return [
+            {k: v for k, v in e.items() if k not in ("ts_ms", "duration_ms")}
+            for e in sink.events
+        ]
+
+    plain, annotated = InMemoryTraceSink(), InMemoryTraceSink(annotate=True)
+    assert annotated.annotate and not plain.annotate
+    assert records(annotated) == records(plain)
+
+
 def test_processor_snapshot_hot_counters_and_attribution():
     proc = CEPProcessor(
         stock_demo.stock_pattern(), 2, stock_cfg(slab_hot_entries=8)
@@ -256,6 +320,7 @@ def test_processor_snapshot_hot_counters_and_attribution():
 TIMING_KEYS = (
     "device_seconds", "decode_seconds", "pack_seconds", "dispatch_seconds",
     "gc_seconds", "events_per_second_device", "event_time_lag_ms", "hbm",
+    "decode_wait_seconds", "decode_build_seconds", "gc_pull_seconds",
     "phases",
     # Latency-ledger segment values are wall clock; observation COUNTS are
     # deterministic and asserted separately (tests/test_latency.py).
