@@ -50,6 +50,8 @@ _RUNTIME_KEYS = (
     "matches_out",
     "duplicates_dropped",
     "decode_fallbacks",
+    "decode_events_built",
+    "decode_events_reused",
 )
 _SECONDS_KEYS = (
     "pack_seconds",

@@ -23,6 +23,7 @@ matches on absolute time and your timestamps are small.
 from __future__ import annotations
 
 import contextlib
+import gc
 import time
 from typing import Any, Dict, Hashable, List, NamedTuple, Optional, Sequence as Seq, Tuple
 
@@ -96,6 +97,22 @@ def _bucket(t: int) -> int:
     while n < t:
         n *= 2
     return n
+
+
+@contextlib.contextmanager
+def _gc_paused():
+    """Hold the cyclic garbage collector off over a bulk build of live
+    objects: every collection it would start there frees nothing, and its
+    older generations walk the whole heap.  The objects stay tracked; the
+    next collection after the block sees them."""
+    if not gc.isenabled():
+        yield
+        return
+    gc.disable()
+    try:
+        yield
+    finally:
+        gc.enable()
 
 
 class CEPProcessor:
@@ -1278,34 +1295,97 @@ class CEPProcessor:
         )
 
     def _build_matches(self, ks, cnts, stages, offs):
-        """Already-ordered hit rows -> (key, Sequence) objects."""
+        """Already-ordered hit rows -> (key, Sequence) objects.
+
+        One batched pass: every row's first ``cnts[i]`` ``(lane, device
+        offset)`` slots are gathered in walk order and deduplicated; each
+        distinct slot is served from the materialized mirror first, and
+        the rest are materialized from the lazy column batches (newest
+        first) with one gather per column, then cached in the mirror in
+        walk order.  Counted in ``decode_events_built`` (materialized
+        here) and ``decode_events_reused`` (every other slot)."""
         names = self.batch.names
-        matches: List[Tuple[Hashable, Sequence]] = []
-        with self._phase("decode_build"):
-            for i in range(ks.size):
-                k = int(ks[i])
-                seq = Sequence()
-                for w in range(int(cnts[i])):
-                    seq.add(
-                        names[int(stages[i, w])],
-                        self._event_at(k, int(offs[i, w])),
-                    )
-                matches.append((self._key_of[k], seq))
+        with self._phase("decode_build"), _gc_paused():
+            if np.size(ks) == 0:
+                return []
+            ks = np.asarray(ks, dtype=np.int64)
+            offs = np.asarray(offs, dtype=np.int64)
+            take = np.arange(offs.shape[1]) < np.asarray(cnts)[:, None]
+            lane = np.broadcast_to(ks[:, None], offs.shape)[take]
+            off = offs[take]
+            _, first, inv = np.unique(
+                lane * OFFSET_LIMIT + off, return_index=True,
+                return_inverse=True,
+            )
+            u_lane, u_off = lane[first], off[first]
+            mirror = self._events
+            events = [
+                mirror[l].get(o)
+                for l, o in zip(u_lane.tolist(), u_off.tolist())
+            ]
+            miss = np.flatnonzero(
+                np.fromiter((e is None for e in events), bool, len(events))
+            )
+            if miss.size:
+                # Walk order of first use: the order the mirror caches them.
+                miss = miss[np.argsort(first[miss])]
+                for j, ev in zip(miss.tolist(), self._materialize_slots(
+                    u_lane[miss], u_off[miss]
+                )):
+                    events[j] = ev
+            self.metrics.decode_events_built += int(miss.size)
+            self.metrics.decode_events_reused += int(off.size - miss.size)
+            slot_events = [events[j] for j in inv.reshape(-1).tolist()]
+            # Runs of slots with one row and one stage, each added to its
+            # row's Sequence in one extend (walk order kept).
+            row = np.nonzero(take)[0]
+            stage = np.asarray(stages)[take]
+            run_start = np.ones(row.size, dtype=bool)
+            run_start[1:] = (row[1:] != row[:-1]) | (stage[1:] != stage[:-1])
+            starts = np.flatnonzero(run_start)
+            seqs = [Sequence() for _ in range(ks.size)]
+            for r, s, a, z in zip(
+                row[starts].tolist(), stage[starts].tolist(),
+                starts.tolist(), starts[1:].tolist() + [row.size],
+            ):
+                seqs[r].extend(names[s], slot_events[a:z])
+            key_of = self._key_of
+            matches = [(key_of[k], seq) for k, seq in zip(ks.tolist(), seqs)]
         return matches
 
-    def _event_at(self, lane: int, off: int) -> Event:
-        """Event by (lane, device offset): the materialized mirror first,
-        then the lazy column batches (newest first), caching on hit."""
-        ev = self._events[lane].get(off)
-        if ev is not None:
-            return ev
+    def _materialize_slots(self, lanes, offs) -> List[Event]:
+        """Events at distinct ``(lanes[i], offs[i])`` device slots, none in
+        the mirror yet, from the lazy column batches (newest first, one
+        fancy-index gather per column); each is cached in the mirror in
+        the order given."""
+        treedef = jax.tree_util.tree_structure(self._value_proto)
+        out: List[Optional[Event]] = [None] * len(offs)
+        todo = np.arange(len(offs))
         for start, cnt, abs_ts, leaves in reversed(self._col_batches):
-            s = int(start[lane])
-            if s >= 0 and s <= off < s + int(cnt[lane]):
-                ev = self._materialize(lane, off, s, abs_ts, leaves)
-                self._events[lane][off] = ev
-                return ev
-        raise KeyError(f"lane {lane} has no event at device offset {off}")
+            if not todo.size:
+                break
+            l, o = lanes[todo], offs[todo]
+            s = start[l]
+            hit = (s >= 0) & (s <= o) & (o < s + cnt[l])
+            if not hit.any():
+                continue
+            l, o, t = l[hit], o[hit], (o - s)[hit]
+            cols = [leaf[l, t].tolist() for leaf in leaves]
+            for i, lv, ts, src, *vals in zip(
+                todo[hit].tolist(), l.tolist(), abs_ts[l, t].tolist(),
+                (o + self._off_base[l]).tolist(), *cols,
+            ):
+                out[i] = Event._of(
+                    self._key_of[lv], treedef.unflatten(vals), ts,
+                    self.topic, lv, src,
+                )
+            todo = todo[~hit]
+        if todo.size:
+            lane, off = int(lanes[todo[0]]), int(offs[todo[0]])
+            raise KeyError(f"lane {lane} has no event at device offset {off}")
+        for lv, ov, ev in zip(lanes.tolist(), offs.tolist(), out):
+            self._events[lv][ov] = ev
+        return out
 
     def _materialize(self, lane, off, start, abs_ts, leaves) -> Event:
         t = off - start

@@ -42,6 +42,22 @@ class Event:
     def __hash__(self) -> int:
         return hash((self.topic, self.partition, self.offset))
 
+    @classmethod
+    def _of(cls, key, value, timestamp, topic, partition, offset) -> "Event":
+        """The constructor without the frozen dataclass's six
+        ``object.__setattr__`` calls, for the decode's bulk builds: the
+        same fields in the same order, so equality, hash, frozenness and
+        pickling are those of ``Event(...)``."""
+        ev = object.__new__(cls)
+        d = ev.__dict__
+        d["key"] = key
+        d["value"] = value
+        d["timestamp"] = timestamp
+        d["topic"] = topic
+        d["partition"] = partition
+        d["offset"] = offset
+        return ev
+
     @property
     def position(self) -> Tuple[str, int, int]:
         return (self.topic, self.partition, self.offset)
@@ -71,6 +87,11 @@ class Sequence:
 
     def add(self, stage: str, event: Event) -> "Sequence":
         self._stages.setdefault(stage, []).append(event)
+        return self
+
+    def extend(self, stage: str, events: Iterable[Event]) -> "Sequence":
+        """``add`` each of ``events`` at ``stage``, in order."""
+        self._stages.setdefault(stage, []).extend(events)
         return self
 
     def get(self, stage: str) -> Optional[List[Event]]:

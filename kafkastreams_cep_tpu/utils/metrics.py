@@ -25,13 +25,18 @@ from kafkastreams_cep_tpu.utils.telemetry import (
     MetricsRegistry,
 )
 
-#: Integer runtime counters, in their historical snapshot order.
+#: Integer runtime counters, in their historical snapshot order.  The
+#: decode's event slots split two ways: ``decode_events_built`` were
+#: materialized from the lazy column batches, ``decode_events_reused``
+#: came from the mirror or an earlier row of the same call.
 COUNTER_ATTRS = (
     "records_in",
     "matches_out",
     "batches",
     "duplicates_dropped",
     "decode_fallbacks",
+    "decode_events_built",
+    "decode_events_reused",
 )
 
 #: Wall-time accumulators; each also feeds the phase histogram of the same
