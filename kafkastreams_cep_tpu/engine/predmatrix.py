@@ -12,8 +12,8 @@ many queries reference it.  Every query's prefix is then a gather of
 one vmapped stencil recurrence (``bank_prefix_scan``).
 
 Bit-identity contract: ``single_prefix_scan`` is the post-predicate math
-of ``engine/stencil.py: StencilPrefix._scan``, verbatim — integer and
-boolean ops only, so vmapping it over a query axis is exact, and a
+of ``engine/stencil.py: StencilPrefix.stencil_prefix_scan``, verbatim —
+integer and boolean ops only, so vmapping it over a query axis is exact, and a
 tenant bank's per-query promotions equal the promotions ``StencilPrefix``
 would have produced for that query alone.  Column values are exact too:
 a *shared* column is provably state-independent (``reads_states``), so
@@ -74,7 +74,7 @@ def build_matrix(
     states env (state-independence is proven, so the env is
     unobservable), private ones their owner's init env.  Values are
     ANDed with ``ev.valid`` so padded slots never fire — the same
-    masking ``StencilPrefix._scan`` applies per stage.
+    masking ``StencilPrefix.stencil_prefix_scan`` applies per stage.
 
     ``disabled`` columns (tenant quarantine — ``parallel/tenantbank.py``
     gates out every column used *only* by quarantined queries) are
@@ -130,9 +130,9 @@ def single_prefix_scan(p: int):
     """The prefix recurrence for one query, predicates already evaluated.
 
     ``scan(carry, bools, offs, ts, valid) -> (carry, PromoOutput)`` is
-    ``StencilPrefix._scan`` from its ``bools`` line down, verbatim — see
-    the module docstring for why that equivalence is the whole
-    correctness argument.
+    ``StencilPrefix.stencil_prefix_scan`` from its ``bools`` line down,
+    verbatim — see the module docstring for why that equivalence is the
+    whole correctness argument.
     """
     i32 = jnp.int32
 

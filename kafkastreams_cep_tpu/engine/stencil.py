@@ -207,6 +207,20 @@ class PrefixCarry(NamedTuple):
     promotions: jnp.ndarray  # [K] int32 — runs injected into the NFA tier
 
 
+def partial_prefix_mask(bools: np.ndarray, offs: np.ndarray) -> np.ndarray:
+    """Host-side ``[K, p-1]`` mask of the carry events a later batch can
+    still complete a prefix with: carry slot ``i`` is pending iff some
+    window start ``a <= i`` has every carry event from ``a`` on matching
+    its stage (``bools[k, m, m - a]``).  A promotion reads exactly these
+    events' offsets, so their host events must outlive the batch."""
+    K, n = offs.shape
+    chain = np.ones((K, n), bool)  # chain[:, a]: a partial prefix from a
+    for a in range(n):
+        for m in range(a, n):
+            chain[:, a] &= bools[:, m, m - a]
+    return np.logical_or.accumulate(chain, axis=1) & (offs >= 0)
+
+
 class PromoOutput(NamedTuple):
     """Per-step promotion feed for the NFA tier: at every batch slot where
     the prefix completed (``fire``), the p prefix-event offsets, the
@@ -280,7 +294,7 @@ class StencilPrefix:
         )
         self.scan = _cached_scan_jit(
             "stencil.prefix_scan", self.tables,
-            (self.num_lanes, self.p), self._scan,
+            (self.num_lanes, self.p), self.stencil_prefix_scan,
         )
 
     def init_carry(self) -> PrefixCarry:
@@ -298,9 +312,11 @@ class StencilPrefix:
             promotions=z,
         )
 
-    def _scan(
+    def stencil_prefix_scan(
         self, carry: PrefixCarry, ev: EventBatch
     ) -> Tuple[PrefixCarry, PromoOutput]:
+        """The body ``scan`` jits; its name names the device program
+        (``jit_stencil_prefix_scan``) in a profiler trace."""
         K, p = self.num_lanes, self.p
         i32 = jnp.int32
         T = ev.ts.shape[-1]

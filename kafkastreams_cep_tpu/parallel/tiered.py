@@ -301,7 +301,9 @@ class TieredBatchMatcher:
             )
             return s, outs, n, needed.astype(i32)
 
-        def scan(eng: EngineState, events: EventBatch, promo):
+        def tiered_suffix_scan(eng: EngineState, events: EventBatch, promo):
+            # Named for the profiler trace (``jit_tiered_suffix_scan``),
+            # apart from the untiered matcher's ``jit_scan``.
             swap = lambda x: jnp.swapaxes(x, 0, 1)
             ev_t = tmap(swap, events)  # leaves [T, K, ...]
             pr_t = tmap(swap, promo)
@@ -356,7 +358,7 @@ class TieredBatchMatcher:
                 self.plan.prefix_len, self.inner.uses_walk_kernel,
                 self.inner._kernel_interpret,
             ),
-            lambda: jax.jit(scan),
+            lambda: jax.jit(tiered_suffix_scan),
         )
 
     @property
@@ -424,11 +426,12 @@ class TieredBatchMatcher:
         else:
             C = max(int(self.matcher.config.gate_chunk), 1)
             self.gate_chunks += -(-T // C)
-            self._nfa_chunks_dev = (
-                dispatched
-                if self._nfa_chunks_dev is None
-                else self._nfa_chunks_dev + dispatched
-            )
+            # Add from the first call on, so the accumulation compiles in
+            # the call that warms the scan up, not in the one after it.
+            prev = self._nfa_chunks_dev
+            if prev is None:
+                prev = jnp.zeros_like(dispatched)
+            self._nfa_chunks_dev = prev + dispatched
         carry = carry._replace(promotions=carry.promotions + promoted)
         return TieredState(eng, carry), out
 

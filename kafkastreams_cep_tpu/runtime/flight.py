@@ -52,6 +52,7 @@ _RUNTIME_KEYS = (
     "decode_fallbacks",
     "decode_events_built",
     "decode_events_reused",
+    "gc_carry_pinned",
 )
 _SECONDS_KEYS = (
     "pack_seconds",

@@ -37,6 +37,8 @@ from kafkastreams_cep_tpu.engine.matcher import (
     EngineConfig,
     EventBatch,
 )
+from kafkastreams_cep_tpu.engine.stencil import partial_prefix_mask
+from kafkastreams_cep_tpu.engine.tiered import engine_view
 from kafkastreams_cep_tpu.parallel.batch import BatchMatcher
 from kafkastreams_cep_tpu.runtime.ingest import (
     REASON_LANE_OVERFLOW,
@@ -1403,23 +1405,40 @@ class CEPProcessor:
 
         The device slab GCs entries by refcount exactly like the reference
         buffer (``KVSharedVersionedBuffer.java:147-171``); the host mirror
-        only needs events still present in a lane's slab or pointed at by a
-        live run, so everything else is released here after each batch.
+        only needs events still present in a lane's slab, pointed at by a
+        live run, or held in a tiered matcher's stencil carry as a partial
+        prefix a later batch may promote (counted in ``gc_carry_pinned``
+        where nothing else holds them), so everything else is released
+        here after each batch.
         Timed as the ``gc`` phase wherever it runs (checkpoints call it
         too), with the liveness transfer as its ``gc_pull`` child.
         """
         with self._phase("gc"):
             # Tiered processors wrap the engine state (engine/tiered.py);
-            # liveness lives in the engine half either way.
-            eng = getattr(self.state, "engine", self.state)
+            # liveness lives in the engine half, plus the stencil carry's
+            # partial prefixes, which own no slab entry until promoted.
+            eng = engine_view(self.state)
+            carry = getattr(self.state, "carry", None)
             with self._phase("gc_pull"):
                 slab_stage = np.asarray(jax.device_get(eng.slab.stage))  # [K, E]
                 slab_off = np.asarray(jax.device_get(eng.slab.off))
                 run_alive = np.asarray(jax.device_get(eng.alive))  # [K, R]
                 run_off = np.asarray(jax.device_get(eng.event_off))
+                if carry is not None:
+                    c_bools, c_offs = (np.asarray(a) for a in jax.device_get(
+                        (carry.bools, carry.offs)))  # [K, p-1, p], [K, p-1]
+            pending, pinned = {}, 0
+            if carry is not None:
+                mask = partial_prefix_mask(c_bools, c_offs)
+                for k in np.flatnonzero(mask.any(axis=1)).tolist():
+                    pending[k] = c_offs[k][mask[k]].tolist()
             for k in range(self.num_lanes):
                 live = set(slab_off[k][slab_stage[k] >= 0].tolist())
                 live.update(run_off[k][run_alive[k]].tolist())
+                held = pending and pending.get(k)
+                if held:
+                    pinned += len(set(held) - live)
+                    live.update(held)
                 # Live rows still sitting in lazy column batches
                 # materialize now (the batches are dropped below); dead
                 # rows never do.
@@ -1438,6 +1457,7 @@ class CEPProcessor:
                 for o in dead:
                     del store[o]
             self._col_batches.clear()
+            self.metrics.gc_carry_pinned += pinned
 
     def lane_shards(self) -> Optional[List[int]]:
         """The live lane→shard assignment (contiguous blocks over the

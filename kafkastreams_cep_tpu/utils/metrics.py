@@ -29,6 +29,9 @@ from kafkastreams_cep_tpu.utils.telemetry import (
 #: decode's event slots split two ways: ``decode_events_built`` were
 #: materialized from the lazy column batches, ``decode_events_reused``
 #: came from the mirror or an earlier row of the same call.
+#: ``gc_carry_pinned`` counts the events the host event GC kept only
+#: because a tiered matcher's stencil carry holds them in a partial
+#: prefix (summed over GC passes).
 COUNTER_ATTRS = (
     "records_in",
     "matches_out",
@@ -37,6 +40,7 @@ COUNTER_ATTRS = (
     "decode_fallbacks",
     "decode_events_built",
     "decode_events_reused",
+    "gc_carry_pinned",
 )
 
 #: Wall-time accumulators; each also feeds the phase histogram of the same

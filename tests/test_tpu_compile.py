@@ -21,12 +21,14 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "examples"))
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
 
 import stock_demo
 from kafkastreams_cep_tpu.engine import EngineConfig, EventBatch, TPUMatcher
 from kafkastreams_cep_tpu.ops import scan_kernel
 from kafkastreams_cep_tpu.parallel import ShardedMatcher
 from kafkastreams_cep_tpu.parallel.batch import broadcast_state, kernel_lane_step
+from kafkastreams_cep_tpu.parallel.tiered import TieredBatchMatcher
 
 K = 1024
 
@@ -126,3 +128,42 @@ def test_sharded_scan_compiles_on_four_chips(topo, monkeypatch):
     _assert_kernel(sharded.scan.lower(
         _state(sharded.matcher, lanes, spread), _events(lanes, 8, spread)
     ))
+
+
+def test_tiered_scan_fits_one_chip_at_the_prefix_cell(one_chip, monkeypatch):
+    """The tiered programs of ``prefix-131072`` at the cell's own shapes
+    (131,072 lanes, calls of 8 steps, the autosized capacity).  The suffix
+    scan's arguments, outputs and temporaries are what the configuration's
+    unreduced lane count rests on: 2,269,642,752 + 2,804,941,824 +
+    6,000,307,200 B (11.07 GB) when this case was written, of the v5e's
+    16 GiB."""
+    from harness import spec
+
+    monkeypatch.setenv("CEP_WALK_KERNEL", "1")
+    conf = spec.load_json(os.path.join(
+        os.path.dirname(__file__), "..", "benchmark", "configs",
+        "prefix-131072.json"))
+    lanes, steps = int(conf["lanes"]), 8
+    tiered = TieredBatchMatcher(
+        spec.build_query(conf["pattern"]), lanes, spec.engine_config(conf))
+    assert tiered.plan.tier == "hybrid" and tiered.uses_walk_kernel
+    state = _shapes(jax.eval_shape(tiered.init_state), one_chip)
+    i32 = jax.ShapeDtypeStruct((lanes, steps), jnp.int32)
+    events = _shapes(EventBatch(
+        key=i32, value={"code": i32}, ts=i32, off=i32,
+        valid=jax.ShapeDtypeStruct((lanes, steps), jnp.bool_),
+    ), one_chip)
+    promo = _shapes(
+        jax.eval_shape(tiered._prefix.scan, state.carry, events)[1], one_chip)
+    # The trace names the programs apart from the untiered ``jit_scan``.
+    prefix = tiered._prefix.scan.lower(state.carry, events).compile()
+    assert "HloModule jit_stencil_prefix_scan" in prefix.as_text()
+    compiled = tiered._hybrid_scan_jit.lower(
+        state.engine, events, promo).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_tiered_suffix_scan" in text
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 16 * 2**30
