@@ -53,6 +53,8 @@ _RUNTIME_KEYS = (
     "decode_events_built",
     "decode_events_reused",
     "gc_carry_pinned",
+    "gc_events_materialized",
+    "gc_lanes_swept",
 )
 _SECONDS_KEYS = (
     "pack_seconds",

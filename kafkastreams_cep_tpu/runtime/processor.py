@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import contextlib
 import gc
+import itertools
 import time
 from typing import Any, Dict, Hashable, List, NamedTuple, Optional, Sequence as Seq, Tuple
 
@@ -115,6 +116,24 @@ def _gc_paused():
         yield
     finally:
         gc.enable()
+
+
+def _slot_keys(mask: np.ndarray, offs: np.ndarray) -> np.ndarray:
+    """``lane * OFFSET_LIMIT + offset`` of every marked slot of a ``[K, n]``
+    offset array that holds a device offset (``0 <= offset <
+    OFFSET_LIMIT``), in lane order."""
+    lane = np.nonzero(mask)[0]
+    off = offs[mask].astype(np.int64)
+    ok = (off >= 0) & (off < OFFSET_LIMIT)
+    return lane[ok] * OFFSET_LIMIT + off[ok]
+
+
+def _rows_in(batch, lanes: np.ndarray, offs: np.ndarray) -> np.ndarray:
+    """Mask of the ``(lanes[i], offs[i])`` device slots whose rows sit in
+    lazy column ``batch`` (its per-lane ``start``, ``-1`` where the lane
+    fed none, and row ``cnt``)."""
+    start, cnt = batch[0][lanes], batch[1][lanes]
+    return (start >= 0) & (start <= offs) & (offs < start + cnt)
 
 
 class CEPProcessor:
@@ -1363,24 +1382,17 @@ class CEPProcessor:
         treedef = jax.tree_util.tree_structure(self._value_proto)
         out: List[Optional[Event]] = [None] * len(offs)
         todo = np.arange(len(offs))
-        for start, cnt, abs_ts, leaves in reversed(self._col_batches):
+        for batch in reversed(self._col_batches):
             if not todo.size:
                 break
-            l, o = lanes[todo], offs[todo]
-            s = start[l]
-            hit = (s >= 0) & (s <= o) & (o < s + cnt[l])
+            hit = _rows_in(batch, lanes[todo], offs[todo])
             if not hit.any():
                 continue
-            l, o, t = l[hit], o[hit], (o - s)[hit]
-            cols = [leaf[l, t].tolist() for leaf in leaves]
-            for i, lv, ts, src, *vals in zip(
-                todo[hit].tolist(), l.tolist(), abs_ts[l, t].tolist(),
-                (o + self._off_base[l]).tolist(), *cols,
-            ):
-                out[i] = Event._of(
-                    self._key_of[lv], treedef.unflatten(vals), ts,
-                    self.topic, lv, src,
-                )
+            at = todo[hit]
+            for i, ev in zip(at.tolist(), self._events_at(
+                batch, treedef, lanes[at], offs[at]
+            )):
+                out[i] = ev
             todo = todo[~hit]
         if todo.size:
             lane, off = int(lanes[todo[0]]), int(offs[todo[0]])
@@ -1389,16 +1401,21 @@ class CEPProcessor:
             self._events[lv][ov] = ev
         return out
 
-    def _materialize(self, lane, off, start, abs_ts, leaves) -> Event:
-        t = off - start
-        dtypes, treedef = jax.tree_util.tree_flatten(self._value_proto)
-        value = jax.tree_util.tree_unflatten(
-            treedef, [l[lane, t].item() for l in leaves]
-        )
-        return Event(
-            self._key_of[lane], value, int(abs_ts[lane, t]), self.topic,
-            lane, off + int(self._off_base[lane]),
-        )
+    def _events_at(self, batch, treedef, lanes, offs) -> List[Event]:
+        """The events of the rows at device slots ``(lanes[i], offs[i])``,
+        all held by column ``batch``: one fancy-index gather and
+        ``.tolist()`` per column, values unflattened with ``treedef``."""
+        start, _, abs_ts, leaves = batch
+        t = offs - start[lanes]
+        key_of, topic = self._key_of, self.topic
+        return [
+            Event._of(key_of[lv], treedef.unflatten(vals), ts, topic, lv, src)
+            for lv, ts, src, *vals in zip(
+                lanes.tolist(), abs_ts[lanes, t].tolist(),
+                (offs + self._off_base[lanes]).tolist(),
+                *(leaf[lanes, t].tolist() for leaf in leaves),
+            )
+        ]
 
     def _gc_events(self) -> None:
         """Drop host events no longer reachable from device state.
@@ -1410,10 +1427,19 @@ class CEPProcessor:
         prefix a later batch may promote (counted in ``gc_carry_pinned``
         where nothing else holds them), so everything else is released
         here after each batch.
+
+        One pass over whole arrays: the live ``(lane, device offset)``
+        slots as sorted ``lane * OFFSET_LIMIT + offset`` keys; the mirror's
+        dead entries dropped on the lanes whose mirror holds any (counted
+        in ``gc_lanes_swept``); then the live rows still in lazy column
+        batches and not in the mirror materialized, batch by batch, with
+        one gather per column (counted in ``gc_events_materialized``)
+        before the batches are dropped.  Dead rows never materialize, and
+        mirror entries keep their identity.
         Timed as the ``gc`` phase wherever it runs (checkpoints call it
         too), with the liveness transfer as its ``gc_pull`` child.
         """
-        with self._phase("gc"):
+        with self._phase("gc"), _gc_paused():
             # Tiered processors wrap the engine state (engine/tiered.py);
             # liveness lives in the engine half, plus the stencil carry's
             # partial prefixes, which own no slab entry until promoted.
@@ -1427,37 +1453,50 @@ class CEPProcessor:
                 if carry is not None:
                     c_bools, c_offs = (np.asarray(a) for a in jax.device_get(
                         (carry.bools, carry.offs)))  # [K, p-1, p], [K, p-1]
-            pending, pinned = {}, 0
+            live = np.union1d(_slot_keys(slab_stage >= 0, slab_off),
+                              _slot_keys(run_alive, run_off))
             if carry is not None:
-                mask = partial_prefix_mask(c_bools, c_offs)
-                for k in np.flatnonzero(mask.any(axis=1)).tolist():
-                    pending[k] = c_offs[k][mask[k]].tolist()
-            for k in range(self.num_lanes):
-                live = set(slab_off[k][slab_stage[k] >= 0].tolist())
-                live.update(run_off[k][run_alive[k]].tolist())
-                held = pending and pending.get(k)
-                if held:
-                    pinned += len(set(held) - live)
-                    live.update(held)
-                # Live rows still sitting in lazy column batches
-                # materialize now (the batches are dropped below); dead
-                # rows never do.
-                for start, cnt, abs_ts, leaves in self._col_batches:
-                    s = int(start[k])
-                    if s < 0:
+                held = np.unique(
+                    _slot_keys(partial_prefix_mask(c_bools, c_offs), c_offs)
+                )
+                self.metrics.gc_carry_pinned += int(
+                    held.size - np.isin(held, live, assume_unique=True).sum()
+                )
+                live = np.union1d(live, held)
+            mirror = self._events
+            sizes = np.fromiter(map(len, mirror), np.int64, len(mirror))
+            cached = np.repeat(
+                np.arange(len(mirror), dtype=np.int64) * OFFSET_LIMIT, sizes
+            ) + np.fromiter(
+                itertools.chain.from_iterable(mirror), np.int64, int(sizes.sum())
+            )
+            at = np.searchsorted(live, cached)
+            kept = at < live.size
+            kept[kept] = live[at[kept]] == cached[kept]
+            dead_lane, dead_off = np.divmod(cached[~kept], OFFSET_LIMIT)
+            for lv, ov in zip(dead_lane.tolist(), dead_off.tolist()):
+                del mirror[lv][ov]
+            self.metrics.gc_lanes_swept += int(np.count_nonzero(sizes))
+            # Live rows still sitting in lazy column batches materialize
+            # now (the batches are dropped below), from the oldest batch
+            # that holds each.
+            if self._col_batches:
+                treedef = jax.tree_util.tree_structure(self._value_proto)
+                todo = live[~np.isin(live, cached[kept], assume_unique=True)]
+                lanes, offs = np.divmod(todo, OFFSET_LIMIT)
+                for batch in self._col_batches:
+                    if not lanes.size:
+                        break
+                    hit = _rows_in(batch, lanes, offs)
+                    if not hit.any():
                         continue
-                    hi = s + int(cnt[k])
-                    for o in live:
-                        if s <= o < hi and o not in self._events[k]:
-                            self._events[k][o] = self._materialize(
-                                k, o, s, abs_ts, leaves
-                            )
-                store = self._events[k]
-                dead = [o for o in store if o not in live]
-                for o in dead:
-                    del store[o]
+                    l, o = lanes[hit], offs[hit]
+                    for lv, ov, ev in zip(l.tolist(), o.tolist(),
+                                          self._events_at(batch, treedef, l, o)):
+                        mirror[lv][ov] = ev
+                    self.metrics.gc_events_materialized += int(l.size)
+                    lanes, offs = lanes[~hit], offs[~hit]
             self._col_batches.clear()
-            self.metrics.gc_carry_pinned += pinned
 
     def lane_shards(self) -> Optional[List[int]]:
         """The live lane→shard assignment (contiguous blocks over the

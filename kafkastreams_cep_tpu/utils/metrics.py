@@ -31,7 +31,10 @@ from kafkastreams_cep_tpu.utils.telemetry import (
 #: came from the mirror or an earlier row of the same call.
 #: ``gc_carry_pinned`` counts the events the host event GC kept only
 #: because a tiered matcher's stencil carry holds them in a partial
-#: prefix (summed over GC passes).
+#: prefix (summed over GC passes); ``gc_events_materialized`` the live
+#: rows the GC materialized from lazy column batches, and
+#: ``gc_lanes_swept`` the lanes whose mirror its dead removal visited
+#: (both summed over GC passes).
 COUNTER_ATTRS = (
     "records_in",
     "matches_out",
@@ -41,6 +44,8 @@ COUNTER_ATTRS = (
     "decode_events_built",
     "decode_events_reused",
     "gc_carry_pinned",
+    "gc_events_materialized",
+    "gc_lanes_swept",
 )
 
 #: Wall-time accumulators; each also feeds the phase histogram of the same
