@@ -1,6 +1,6 @@
 """Chip smoke: the served CEP path once, on the chip, as a user drives it.
 
-    python chip_smoke.py [--seed N]             # one chip, phases 1-5
+    python chip_smoke.py [--seed N]             # one chip, phases 1-4
     python chip_smoke.py --chips 4 [--seed N]   # the key-sharded mesh only
 
 One chip runs, in order:
@@ -15,10 +15,7 @@ One chip runs, in order:
    and restore, and the emissions must equal an uninterrupted processor's;
    every loss counter stays 0;
 4. oracle parity — sampled lanes replayed through ``OracleNFA`` give the
-   device's emissions exactly;
-5. whole-scan kernel — one batch through ``CEP_SCAN_KERNEL=1`` is
-   bit-equal (outputs and final state) to the walk-kernel path, with no
-   fallback.
+   device's emissions exactly.
 
 ``--chips 4`` runs only the mesh phase: 16384 lanes sharded over four
 chips against a single-device processor on the same stream, with a
@@ -43,13 +40,12 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "examples"))
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 import stock_demo
 from kafkastreams_cep_tpu import OracleNFA, native
-from kafkastreams_cep_tpu.engine import EngineConfig, EventBatch
-from kafkastreams_cep_tpu.parallel import BatchMatcher, key_mesh
+from kafkastreams_cep_tpu.engine import EngineConfig
+from kafkastreams_cep_tpu.parallel import key_mesh
 from kafkastreams_cep_tpu.runtime import CEPProcessor
 from kafkastreams_cep_tpu.runtime.checkpoint import (
     restore_processor,
@@ -260,43 +256,6 @@ def phase_oracle(planes, want, seed: int, lanes: int) -> None:
         f"{time.perf_counter() - t0:.3f} s)")
 
 
-def phase_scan_kernel(planes, lanes: int) -> None:
-    price, volume = planes[0]
-    T = price.shape[0]
-    grid = lambda x: jnp.asarray(x.T, jnp.int32)
-    steps = np.broadcast_to(np.arange(T, dtype=np.int32), (lanes, T))
-    events = EventBatch(
-        key=jnp.asarray(np.broadcast_to(
-            np.arange(lanes, dtype=np.int32)[:, None], (lanes, T))),
-        value={"price": grid(price), "volume": grid(volume)},
-        ts=jnp.asarray(steps),
-        off=jnp.asarray(steps),
-        valid=jnp.ones((lanes, T), bool),
-    )
-    walk = BatchMatcher(stock_demo.stock_pattern(), lanes, CONFIG)
-    os.environ["CEP_SCAN_KERNEL"] = "interpret" if interpret() else "1"
-    try:
-        fused = BatchMatcher(stock_demo.stock_pattern(), lanes, CONFIG)
-    finally:
-        del os.environ["CEP_SCAN_KERNEL"]
-    require(fused.uses_scan_kernel, "whole-scan kernel not selected")
-    t0 = time.perf_counter()
-    want = walk.scan(walk.init_state(), events)
-    jax.block_until_ready(want)
-    t1 = time.perf_counter()
-    got = fused.scan(fused.init_state(), events)
-    jax.block_until_ready(got)
-    t2 = time.perf_counter()
-    require(fused.uses_scan_kernel, "whole-scan kernel fell back")
-    same = jax.tree_util.tree_map(
-        lambda a, b: bool(jnp.array_equal(a, b)), want, got
-    )
-    require(all(jax.tree_util.tree_leaves(same)),
-            "whole-scan kernel differs from the walk kernel")
-    say(f"whole-scan kernel bit-equal to walk kernel on {lanes}x{T} "
-        f"(walk {t1 - t0:.3f} s, fused {t2 - t1:.3f} s, compile included)")
-
-
 def phase_mesh(planes, devs) -> None:
     mesh = key_mesh(devs[:4])
     single = CEPProcessor(
@@ -337,7 +296,6 @@ def main(argv=None) -> int:
         planes = stream(args.seed, LANES)
         want = phase_stream(planes, LANES)
         phase_oracle(planes, want, args.seed, LANES)
-        phase_scan_kernel(planes, LANES)
     say(f"all phases passed in {time.perf_counter() - t0:.1f} s")
     dev = devs[0]
     print(json.dumps({"ok": True, "device": {

@@ -158,12 +158,12 @@ def _alloc(slab: SlabState):
 # so results are placement-independent; this jnp path therefore keeps its
 # full-slab masked lookups (under XLA both tiers would be computed anyway)
 # and only *accounts* tier residency via the hot_hits / hot_misses /
-# overflow_walks counters.  The Pallas kernels (``ops/walk_kernel.py``,
-# ``ops/scan_kernel.py``) exploit the same layout structurally: the per-hop
-# reduce runs over the hot rows only and the overflow rows are touched
-# under a block-level ``pl.when`` that skips entirely when every lane of
-# the block resolved hot — the E-linear hop cost drops to E_hot-linear on
-# the common path (PERF.md, walk-pass cost model).
+# overflow_walks counters.  The Pallas walk kernel (``ops/walk_kernel.py``)
+# exploits the same layout structurally: the per-hop reduce runs over the
+# hot rows only and the overflow rows are touched under a block-level
+# ``pl.when`` that skips entirely when every lane of the block resolved
+# hot — the E-linear hop cost drops to E_hot-linear on the common path
+# (PERF.md, walk-pass cost model).
 # ---------------------------------------------------------------------------
 
 

@@ -33,54 +33,6 @@ from kafkastreams_cep_tpu.utils.logging import get_logger
 
 logger = get_logger("parallel.batch")
 
-# Exception-type names and message fragments that identify a Mosaic/Pallas
-# lowering or compilation failure — the only failure class that justifies
-# permanently abandoning the fused kernel for a pattern.  Everything else
-# (RESOURCE_EXHAUSTED on a transient OOM, cancelled/interrupted calls,
-# data-dependent runtime faults) must propagate and leave the kernel armed.
-_LOWERING_ERROR_TYPES = (NotImplementedError,)
-_LOWERING_ERROR_TYPE_NAMES = (
-    "LoweringError",
-    "LoweringException",
-    "MosaicError",
-    "VerificationError",
-)
-_LOWERING_ERROR_MARKERS = (
-    "mosaic",
-    "pallas",
-    "lowering",
-    "unsupported",
-    "not implemented",
-    "cannot lower",
-    "vmem",
-    "relayout",
-    "bitcast_vreg",
-)
-_TRANSIENT_MARKERS = (
-    "resource_exhausted",
-    "interrupted",
-    "cancelled",
-    "deadline",
-    "unavailable",
-)
-
-
-def is_lowering_error(e: BaseException) -> bool:
-    """Classify an exception from a fused-kernel call: ``True`` for
-    Mosaic/Pallas lowering/compilation failures (pattern cannot lower —
-    fall back permanently), ``False`` for anything transient or unknown
-    (re-raise; the kernel stays enabled for the next call)."""
-    if isinstance(e, _LOWERING_ERROR_TYPES):
-        return True
-    msg = str(e).lower()
-    if any(m in msg for m in _TRANSIENT_MARKERS):
-        return False
-    for cls in type(e).__mro__:
-        if cls.__name__ in _LOWERING_ERROR_TYPE_NAMES:
-            return True
-    return any(m in msg for m in _LOWERING_ERROR_MARKERS)
-
-
 def broadcast_state(state: EngineState, num_lanes: int) -> EngineState:
     """Tile one lane's engine state to a ``[K]`` leading axis."""
     return jax.tree_util.tree_map(
@@ -105,46 +57,6 @@ def lane_scan(step_one):
         return jax.vmap(lambda s, e: jax.lax.scan(step_one, s, e))(
             state, events
         )
-
-    return scan
-
-
-def guarded_scan_fallback(fast, make_slow, on_fallback=None,
-                          what="whole-scan kernel"):
-    """Guarded first call of a fused whole-scan kernel, shared by
-    :class:`BatchMatcher` and ``parallel/sharding.ShardedMatcher`` so the
-    failure-classification policy can never drift between the single-chip
-    and sharded paths.
-
-    The kernel traces user predicates into the Pallas program, so a
-    pattern that cannot lower to Mosaic fails at the first *compiled*
-    call, not at build time — only that class of failure
-    (:func:`is_lowering_error`) permanently swaps in ``make_slow()``.
-    Anything transient — device OOM, interrupts, preemption, an injected
-    device fault — re-raises with the kernel still armed, so the next
-    call (e.g. a supervisor recovery retry) runs the fused path again
-    instead of silently degrading for the rest of the process.
-    ``on_fallback`` (if given) runs once at the permanent swap, for the
-    owner's ``uses_scan_kernel`` bookkeeping.
-    """
-    slow = None
-
-    def scan(state, events):
-        nonlocal slow
-        if slow is None:
-            try:
-                return fast(state, events)
-            except Exception as e:
-                if not is_lowering_error(e):
-                    raise
-                logger.warning(
-                    "%s failed to lower (%s); falling back to the "
-                    "per-step path", what, e,
-                )
-                slow = make_slow()
-                if on_fallback is not None:
-                    on_fallback()
-        return slow(state, events)
 
     return scan
 
@@ -347,49 +259,11 @@ class BatchMatcher:
             self._step_fn = lane_step(self.matcher._step_fn)
             self._scan_fn = lane_scan(self.matcher._step_fn)
             self._mode_tag = ("jnp",)
-        # Whole-scan fused kernel (ops/scan_kernel.py): the entire event
-        # loop in one Pallas program, state resident in VMEM across T.
-        # Opt-in (CEP_SCAN_KERNEL=1, or =interpret for CPU testing):
-        # differential parity is pinned by tests/test_scan_kernel.py, and
-        # measured throughput is at parity with the per-step walk kernel
-        # on the headline trace (PERF.md, walk-pass cost model — both are
-        # bound by the same lockstep walk-pass vector work, not launch or
-        # HBM overheads), so the per-step path stays the default.
-        self.uses_scan_kernel = False
-        scan_mode = os.environ.get("CEP_SCAN_KERNEL", "0")
-        if scan_mode in ("1", "interpret"):
-            from kafkastreams_cep_tpu.ops import scan_kernel
-
-            if self.num_lanes % scan_kernel.LANE_BLOCK:
-                logger.warning(
-                    "CEP_SCAN_KERNEL=%s requested but num_lanes=%d is not "
-                    "a multiple of %d — using the per-step path",
-                    scan_mode, self.num_lanes, scan_kernel.LANE_BLOCK,
-                )
-            else:
-                def _build_full(scan_mode=scan_mode):
-                    full = scan_kernel.build_scan(
-                        self.matcher.tables, self.matcher.config
-                    )
-                    full.interpret = scan_mode == "interpret"
-                    return jax.jit(full)
-
-                jitted_full = self._cached(
-                    "batch.scan_kernel", ("scan", scan_mode), _build_full
-                )
-                self._scan_fn = self._with_fallback(jitted_full)
-                self.uses_scan_kernel = True
-                logger.info("batch matcher: whole-scan kernel enabled")
         self.step = self._cached(
             "batch.step", self._mode_tag, lambda: jax.jit(self._step_fn)
         )
-        self.scan = (
-            self._scan_fn
-            if self.uses_scan_kernel
-            else self._cached(
-                "batch.scan", self._mode_tag,
-                lambda: jax.jit(self._scan_fn),
-            )
+        self.scan = self._cached(
+            "batch.scan", self._mode_tag, lambda: jax.jit(self._scan_fn)
         )
         # Measured per-conjunct selectivity: under stage_attribution every
         # consuming-edge conjunct is tallied unconditionally over each
@@ -430,28 +304,6 @@ class BatchMatcher:
 
         key = None if self._cache_key is None else self._cache_key + (tag,)
         return tracecache.lookup(namespace, key, build)
-
-    def _with_fallback(self, jitted_full_scan):
-        """:func:`guarded_scan_fallback` over this matcher's per-step
-        path — see the helper for the failure-classification policy."""
-
-        def make_slow():
-            if self.uses_walk_kernel:
-                return self._cached(
-                    "batch.scan", self._mode_tag,
-                    lambda: jax.jit(kernel_lane_scan(self._step_fn)),
-                )
-            return self._cached(
-                "batch.scan", self._mode_tag,
-                lambda: jax.jit(lane_scan(self.matcher._step_fn)),
-            )
-
-        def on_fallback():
-            self.uses_scan_kernel = False
-
-        return guarded_scan_fallback(
-            jitted_full_scan, make_slow, on_fallback
-        )
 
     @property
     def names(self):

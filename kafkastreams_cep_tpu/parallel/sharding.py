@@ -35,15 +35,11 @@ from kafkastreams_cep_tpu.engine.matcher import (
 from kafkastreams_cep_tpu.parallel.batch import (
     _select_walk_kernel,
     broadcast_state,
-    guarded_scan_fallback,
     kernel_lane_scan,
     kernel_lane_step,
     lane_scan,
     lane_step,
 )
-from kafkastreams_cep_tpu.utils.logging import get_logger
-
-logger = get_logger("parallel.sharding")
 
 
 class ShardLost(RuntimeError):
@@ -135,28 +131,6 @@ class ShardedMatcher:
         else:
             local_step = lane_step(self.matcher._step_fn)
             local_scan = lane_scan(self.matcher._step_fn)
-        # Whole-scan kernel inside shard_map (opt-in, same knob as
-        # BatchMatcher): lanes never cross shards, so each shard's block
-        # is an ordinary lane batch for the fused program.
-        self.uses_scan_kernel = False
-        fallback_local_scan = local_scan
-        scan_mode = __import__("os").environ.get("CEP_SCAN_KERNEL", "0")
-        if scan_mode in ("1", "interpret"):
-            from kafkastreams_cep_tpu.ops import scan_kernel
-
-            if (self.num_lanes // n) % scan_kernel.LANE_BLOCK == 0:
-                full = scan_kernel.build_scan(
-                    self.matcher.tables, self.matcher.config
-                )
-                full.interpret = scan_mode == "interpret"
-                local_scan = full
-                self.uses_scan_kernel = True
-            else:
-                logger.warning(
-                    "CEP_SCAN_KERNEL=%s requested but per-shard lane count "
-                    "%d is not a multiple of %d — using the per-step path",
-                    scan_mode, self.num_lanes // n, scan_kernel.LANE_BLOCK,
-                )
 
         def local_stats(state):
             local = jnp.stack(
@@ -174,32 +148,8 @@ class ShardedMatcher:
             f, mesh=mesh, in_specs=spec, out_specs=out_specs, check_vma=False
         )
         self.step = jax.jit(shard(local_step, spec))
-        if self.uses_scan_kernel:
-            # Same guarded first call as BatchMatcher._with_fallback: the
-            # kernel traces user predicates, so a pattern that cannot lower
-            # to Mosaic fails at the first compiled call — fall back to the
-            # per-step sharded path then, and only then (transient runtime
-            # errors propagate and leave the kernel armed).
-            self.scan = self._scan_with_fallback(
-                jax.jit(shard(local_scan, spec)),
-                lambda: jax.jit(shard(fallback_local_scan, spec)),
-            )
-        else:
-            self.scan = jax.jit(shard(local_scan, spec))
+        self.scan = jax.jit(shard(local_scan, spec))
         self._stats = jax.jit(shard(local_stats, P()))
-
-    def _scan_with_fallback(self, fast, make_slow):
-        """:func:`parallel.batch.guarded_scan_fallback` — one shared
-        classification policy with the single-chip matcher, so a
-        transient device error on the sharded kernel path retries with
-        the kernel armed instead of permanently disabling it."""
-
-        def on_fallback():
-            self.uses_scan_kernel = False
-
-        return guarded_scan_fallback(
-            fast, make_slow, on_fallback, what="sharded whole-scan kernel"
-        )
 
     @property
     def names(self):

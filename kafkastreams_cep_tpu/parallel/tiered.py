@@ -29,9 +29,10 @@ NFA work runs under a ``lax.cond`` — a chunk with no live suffix run and
 no prefix completion advances ``step_seq`` in one op and emits a zero
 output block.  The scan issues **zero per-scan host syncs**: dispatch
 accounting accumulates on device and reaches the host only at telemetry
-reads (:attr:`TieredBatchMatcher.nfa_dispatches`), so pipelined
-processors keep full dispatch/decode overlap under tiering (the old
-design paid one scalar ``device_get`` per scan to decide the skip on
+reads (:attr:`TieredBatchMatcher.nfa_dispatches`, whose only
+host-counted part is a pure-NFA plan's whole-batch dispatches), so
+pipelined processors keep full dispatch/decode overlap under tiering (the
+old design paid one scalar ``device_get`` per scan to decide the skip on
 host).  The skip is exact for any ``gate_chunk``: promotion happens
 *after* the completing step — exactly the untiered schedule — so a
 completion in chunk ``i`` has its first observable NFA effect inside
@@ -40,15 +41,7 @@ chunk) never skips.
 
 Parity: matches, emission order, and loss counters are bit-identical to
 the untiered engine on loss-free workloads across the jnp and Pallas
-walk-kernel paths (tests/test_tiering.py).  Under ``CEP_SCAN_KERNEL``
-the hybrid tier runs a *native tiered whole-scan program*
-(``ops/scan_kernel.py: build_scan(..., promotion=p)``): the stencil
-feed's per-step promotion inputs join the event stream, and the
-promotion's slab writes + run-queue append run as a fused phase after
-the engine phases, gated per step on device — no per-step fallback.  A
-pattern that cannot lower to Mosaic falls back permanently to the
-chunked per-step path (the same failure policy as the untiered kernel,
-``parallel/batch.py: guarded_scan_fallback``).
+walk-kernel paths (tests/test_tiering.py).
 """
 
 from __future__ import annotations
@@ -61,7 +54,6 @@ import jax.numpy as jnp
 
 from kafkastreams_cep_tpu.compiler.tables import TransitionTables, lower
 from kafkastreams_cep_tpu.compiler.tiering import (
-    TIER_HYBRID,
     TIER_NFA,
     TIER_STENCIL,
     TieringPlan,
@@ -133,7 +125,6 @@ class TieredBatchMatcher:
         self.inner = BatchMatcher(tables, num_lanes, config)
         self.matcher = self.inner.matcher
         self.uses_walk_kernel = self.inner.uses_walk_kernel
-        self.uses_scan_kernel = False
         logger.info(
             "tiered matcher: %s (%s), %d lanes",
             self.plan.tier, self.plan.reason, self.num_lanes,
@@ -145,7 +136,7 @@ class TieredBatchMatcher:
         # in with a single transfer at telemetry-read time.
         self.scan_calls = 0
         self.gate_chunks = 0  # device-gated chunks offered (bench denom)
-        self._nfa_dispatch_host = 0  # whole-batch dispatches (nfa/kernel)
+        self._nfa_dispatch_host = 0  # whole-batch dispatches (nfa tier)
         self._nfa_chunks_dev = None  # [*] i32 — chunks that ran NFA work
         p = self.plan.prefix_len
         if self.plan.tier == TIER_NFA:
@@ -160,35 +151,6 @@ class TieredBatchMatcher:
                         stencil_step_output(tables, config, p)
                     ),
                 )
-            if (
-                self.plan.tier == TIER_HYBRID
-                and self.inner.uses_scan_kernel
-            ):
-                # Native tiered whole-scan program: the promotion feed
-                # joins the event stream and the promotion phase fuses
-                # after the engine phases (ops/scan_kernel.py), gated
-                # per step on device.  Same guarded-fallback policy as
-                # the untiered kernel: only a lowering failure swaps in
-                # the chunked per-step path permanently.
-                import os as _os
-
-                scan_mode = _os.environ.get("CEP_SCAN_KERNEL", "0")
-
-                def _build_tiered_full(scan_mode=scan_mode, p=p):
-                    from kafkastreams_cep_tpu.ops import scan_kernel
-
-                    full = scan_kernel.build_scan(
-                        self.tables, self.matcher.config, promotion=p
-                    )
-                    full.interpret = scan_mode == "interpret"
-                    return jax.jit(full)
-
-                self._kernel_scan_jit = self._cached(
-                    "tiered.scan_kernel", (p, scan_mode),
-                    _build_tiered_full,
-                )
-                self.uses_scan_kernel = True
-                logger.info("tiered matcher: whole-scan kernel enabled")
 
     # -- state ---------------------------------------------------------------
 
@@ -363,33 +325,15 @@ class TieredBatchMatcher:
 
     @property
     def nfa_dispatches(self) -> int:
-        """NFA-tier dispatch count: whole-batch dispatches (pure-NFA
-        plans and the tiered whole-scan kernel) plus device-gated chunks
-        that actually ran NFA work.  Reading it is the only host sync in
-        the dispatch accounting (telemetry/bench only — never on the
-        scan path)."""
+        """NFA-tier dispatch count: whole-batch dispatches of a pure-NFA
+        plan (the only host-counted ones) plus device-gated chunks that
+        actually ran NFA work.  Reading it is the only host sync in the
+        dispatch accounting (telemetry/bench only — never on the scan
+        path)."""
         n = self._nfa_dispatch_host
         if self._nfa_chunks_dev is not None:
             n += int(jax.device_get(self._nfa_chunks_dev))
         return n
-
-    def _kernel_scan(self, eng: EngineState, events: EventBatch, promo):
-        """The tiered whole-scan kernel with the guarded permanent
-        fallback (lowering failures only) onto the chunked path."""
-        from kafkastreams_cep_tpu.parallel.batch import is_lowering_error
-
-        try:
-            eng, out, promoted = self._kernel_scan_jit(eng, events, promo)
-            return eng, out, promoted, None
-        except Exception as e:
-            if not is_lowering_error(e):
-                raise
-            logger.warning(
-                "tiered whole-scan kernel failed to lower (%s); falling "
-                "back to the chunk-gated per-step path", e,
-            )
-            self.uses_scan_kernel = False
-            return self._hybrid_scan_jit(eng, events, promo)
 
     def scan(self, state: TieredState, events: EventBatch):
         """One ``[K, T]`` batch through the tier plan.  Same output
@@ -412,26 +356,17 @@ class TieredBatchMatcher:
             out = self._synth(promo)
             eng = self._bump_jit(state.engine, jnp.int32(T))
             return TieredState(eng, carry), out
-        if self.uses_scan_kernel:
-            eng, out, promoted, dispatched = self._kernel_scan(
-                state.engine, events, promo
-            )
-        else:
-            eng, out, promoted, dispatched = self._hybrid_scan_jit(
-                state.engine, events, promo
-            )
-        if dispatched is None:
-            # Whole-scan kernel: one launch, gated per step in-program.
-            self._nfa_dispatch_host += 1
-        else:
-            C = max(int(self.matcher.config.gate_chunk), 1)
-            self.gate_chunks += -(-T // C)
-            # Add from the first call on, so the accumulation compiles in
-            # the call that warms the scan up, not in the one after it.
-            prev = self._nfa_chunks_dev
-            if prev is None:
-                prev = jnp.zeros_like(dispatched)
-            self._nfa_chunks_dev = prev + dispatched
+        eng, out, promoted, dispatched = self._hybrid_scan_jit(
+            state.engine, events, promo
+        )
+        C = max(int(self.matcher.config.gate_chunk), 1)
+        self.gate_chunks += -(-T // C)
+        # Add from the first call on, so the accumulation compiles in
+        # the call that warms the scan up, not in the one after it.
+        prev = self._nfa_chunks_dev
+        if prev is None:
+            prev = jnp.zeros_like(dispatched)
+        self._nfa_chunks_dev = prev + dispatched
         carry = carry._replace(promotions=carry.promotions + promoted)
         return TieredState(eng, carry), out
 

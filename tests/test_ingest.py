@@ -4,9 +4,9 @@ Contracts under test:
 
 1. *Bounded-skew absorption*: any shuffle of a trace whose timestamp
    inversions are bounded by the grace drains **bit-identical matches,
-   emission order, and loss counters** to the in-order run — on the jnp,
-   fused walk-kernel, and whole-scan kernel paths (interpret mode; CPU
-   CI checks parity, not perf).
+   emission order, and loss counters** to the in-order run — on the jnp
+   and fused walk-kernel paths (interpret mode; CPU CI checks parity,
+   not perf).
 2. *Per-record quarantine*: schema/lane/time defects and too-late events
    are diverted to the dead-letter queue with typed reasons — never a
    batch-level exception in the default mode; ``on_bad_record="raise"``
@@ -116,24 +116,13 @@ def test_bounded_skew_shuffle_is_bit_identical_jnp(pattern, seed):
     assert not any(p_ref._guard.loss_counters().values())
 
 
-@pytest.mark.parametrize(
-    "env,mode",
-    [
-        ("CEP_WALK_KERNEL", "interpret"),
-        # Scan-kernel interpret parity is tier-2 (-m slow, ~15 s); the
-        # walk-kernel variant keeps interpret coverage in tier-1
-        # (ROADMAP tier-1 budget note, PR 13).
-        pytest.param(
-            "CEP_SCAN_KERNEL", "interpret", marks=pytest.mark.slow
-        ),
-    ],
-)
+@pytest.mark.parametrize("env,mode", [("CEP_WALK_KERNEL", "interpret")])
 def test_bounded_skew_shuffle_is_bit_identical_kernel(env, mode):
-    """The same parity through the Pallas walk/scan kernels (128-lane
-    floor is the kernels' LANE_BLOCK).  The in-order reference runs on
-    the jnp path — jnp↔kernel parity is pinned by the kernel suites, so
-    this closes the triangle: shuffled-through-kernel ≡ in-order-jnp.
-    Trace kept small: interpret-mode whole-scan cost scales with T."""
+    """The same parity through the Pallas walk kernel (128-lane floor is
+    the kernel's LANE_BLOCK).  The in-order reference runs on the jnp
+    path — jnp↔kernel parity is pinned by the kernel suite, so this
+    closes the triangle: shuffled-through-kernel ≡ in-order-jnp.
+    Trace kept small: interpret-mode cost scales with T."""
     recs = trace([sc.A, sc.B, sc.C, sc.X, sc.A, sc.B, sc.C],
                  keys=("k0", "k1"))
     p_ref, m_ref = run_guarded(sc.strict3(), recs, num_lanes=128, batch=7)
@@ -145,10 +134,7 @@ def test_bounded_skew_shuffle_is_bit_identical_kernel(env, mode):
             sc.strict3(), bounded_shuffle(recs, GRACE, 5), num_lanes=128,
             batch=7,
         )
-        if env == "CEP_WALK_KERNEL":
-            assert p_sh.batch.uses_walk_kernel
-        else:
-            assert p_sh.batch.uses_scan_kernel
+        assert p_sh.batch.uses_walk_kernel
     finally:
         os.environ[env] = "0"
     assert m_sh == m_ref

@@ -187,32 +187,17 @@ def test_ledger_on_off_parity_jnp():
     assert p_on.ledger.records_committed == len(VALS)
 
 
-@pytest.mark.parametrize(
-    "env,mode",
-    [
-        ("CEP_WALK_KERNEL", "interpret"),
-        # The scan-kernel interpret variant is tier-2 (-m slow): it
-        # re-executes the whole scan per step in Python (~104 s); the
-        # walk-kernel variant keeps interpret parity in tier-1
-        # (ROADMAP tier-1 budget note, PR 13).
-        pytest.param(
-            "CEP_SCAN_KERNEL", "interpret", marks=pytest.mark.slow
-        ),
-    ],
-)
+@pytest.mark.parametrize("env,mode", [("CEP_WALK_KERNEL", "interpret")])
 def test_ledger_on_off_parity_kernels(env, mode):
-    """The same parity through the Pallas walk/scan kernels (interpret
-    mode; 128-lane floor is the kernels' LANE_BLOCK).  Stamps are
+    """The same parity through the Pallas walk kernel (interpret mode;
+    128-lane floor is the kernel's LANE_BLOCK).  Stamps are
     host-side, so the kernel path must be byte-for-byte unaffected."""
     vals = [sc.A, sc.B, sc.C, sc.X, sc.A, sc.B, sc.C]
     p_on, m_on = _run(
         True, clock=FakeClock(), env=(env, mode), num_lanes=128, vals=vals
     )
     p_off, m_off = _run(None, env=(env, mode), num_lanes=128, vals=vals)
-    if env == "CEP_WALK_KERNEL":
-        assert p_on.batch.uses_walk_kernel
-    else:
-        assert p_on.batch.uses_scan_kernel
+    assert p_on.batch.uses_walk_kernel
     assert m_on == m_off and m_on  # non-vacuous
     assert p_on.batch.counters(p_on.state) == p_off.batch.counters(
         p_off.state

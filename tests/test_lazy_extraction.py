@@ -6,7 +6,7 @@ The contract (engine/matcher.py):
 1. *Match parity*: with a handle ring sized for the trace
    (``handle_overflows == 0``), the drained match set — sequences, event
    offsets, completion order — is identical to the eager engine's, on the
-   jnp path, the fused walk-kernel path, and the whole-scan kernel path.
+   jnp path and the fused walk-kernel path.
 2. *Loss parity*: every pre-existing loss counter is bit-identical to the
    eager engine on loss-free traces, and ``handle_overflows`` preserves
    the all-zero ⇒ loss-free contract (a full ring drops the match and
@@ -347,42 +347,6 @@ def test_walk_kernel_lazy_parity_under_pressure():
     assert_lazy_same_run(ref, st_r, d_r, krn, st_k, d_k)
     assert ref.hot_counters(st_r)["slab_demotions"] > 0
     assert ref.walk_counters(st_r)["drain_hops"] > 0
-
-
-@pytest.mark.slow
-def test_scan_kernel_lazy_parity_under_pressure():
-    # Tier-2 (-m slow, ~11 s interpret): the walk-kernel variant above
-    # keeps kernel lazy-parity in tier-1 (ROADMAP tier-1 budget note,
-    # PR 13).
-    from kafkastreams_cep_tpu.compiler.tables import lower
-    from kafkastreams_cep_tpu.ops.scan_kernel import build_scan
-
-    K, T = 128, 8
-    events = stock_events(K, T, 31)
-    os.environ["CEP_WALK_KERNEL"] = "0"
-    ref = BatchMatcher(stock_demo.stock_pattern(), K, PRESSURE_LAZY)
-    scan = build_scan(lower(stock_demo.stock_pattern()), PRESSURE_LAZY)
-    scan.interpret = True
-    st_r, _ = ref.scan(ref.init_state(), events)
-    st_k, _ = scan(ref.init_state(), events)
-    # Ring parity BEFORE drain: the in-kernel append is bit-identical.
-    for f in ("hr_stage", "hr_off", "hr_ver", "hr_vlen", "hr_ts",
-              "hr_seq", "hr_row", "hr_count", "step_seq",
-              "handle_overflows"):
-        a = np.asarray(getattr(st_r, f))
-        b = np.asarray(getattr(st_k, f))
-        if f.startswith("hr_") and f not in ("hr_count",):
-            pend = (
-                np.arange(a.shape[1])[None, :]
-                < np.asarray(st_r.hr_count)[:, None]
-            )
-            if a.ndim == 3:
-                pend = pend[..., None]
-            a, b = np.where(pend, a, 0), np.where(pend, b, 0)
-        np.testing.assert_array_equal(a, b, err_msg=f)
-    st_r, d_r = ref.drain(st_r)
-    st_k, d_k = ref.drain(st_k)
-    assert_lazy_same_run(ref, st_r, d_r, ref, st_k, d_k)
 
 
 # ---------------------------------------------------------------------------

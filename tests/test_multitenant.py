@@ -5,8 +5,7 @@ The contract under test (``compiler/multitenant.py`` +
 ``runtime/tenant.py``): N queries sharing one predicate matrix and one
 stencil screen emit, per query, *bit-identical* matches, emission order,
 and loss counters to that query running alone on its own serial matcher
-— across the jnp path, the fused walk kernel, and with the serial
-reference on the whole-scan kernel path.  Durability rides the same
+— across the jnp path and the fused walk kernel.  Durability rides the same
 checkpoint idioms as the single-query runtime: a live shared-prefix
 carry survives save/restore and capacity widening, and the tenant
 supervisor recovers a chaos schedule exactly-once.
@@ -179,14 +178,6 @@ def test_tenant_bank_matches_serial_walk_kernel(monkeypatch):
     patterns = [q_hybrid(8, 3, 9), q_hybrid(9, 1, 7)]
     assert _select_walk_kernel(CFG, 2 * 64) == (True, True)
     assert_bank_parity(patterns, K=64, T=12, n_batches=2, seed0=5)
-
-
-def test_tenant_bank_matches_serial_scan_kernel(monkeypatch):
-    """Serial reference on the whole-scan kernel path (interpret): the
-    deduplicated predicate plan must agree across implementations."""
-    monkeypatch.setenv("CEP_WALK_KERNEL", "0")
-    monkeypatch.setenv("CEP_SCAN_KERNEL", "interpret")
-    assert_bank_parity(MIXED[:3], K=4, T=16, n_batches=2, seed0=11)
 
 
 @pytest.mark.parametrize(

@@ -10,8 +10,8 @@ Three layers of proof:
    is symmetric back to L0, a crash at any brownout level resumes in the
    same level with the actuators re-applied, and a fault injected
    mid-transition leaves the previous level authoritative.
-3. *Differential proof* — on the jnp, walk-kernel, and scan-kernel
-   paths, the survivor stream of a browned-out run is bit-equal to an
+3. *Differential proof* — on the jnp and walk-kernel paths, the
+   survivor stream of a browned-out run is bit-equal to an
    unloaded run over the same admitted subset (determined post hoc from
    the typed ``overload_shed`` dead letters).
 
@@ -533,22 +533,11 @@ def test_survivor_stream_differential_jnp(tmp_path):
     assert_survivor_differential(4, tmp_path, "diffjnp")
 
 
-@pytest.mark.parametrize(
-    "env,mode",
-    [
-        ("CEP_WALK_KERNEL", "interpret"),
-        # Scan-kernel interpret differential is tier-2 (-m slow, ~46 s);
-        # the jnp + walk-kernel differentials keep the proof in tier-1
-        # (ROADMAP tier-1 budget note, PR 13).
-        pytest.param(
-            "CEP_SCAN_KERNEL", "interpret", marks=pytest.mark.slow
-        ),
-    ],
-)
+@pytest.mark.parametrize("env,mode", [("CEP_WALK_KERNEL", "interpret")])
 def test_survivor_stream_differential_kernels(tmp_path, env, mode):
-    """The same proof through the Pallas walk/scan kernels (interpret
-    mode; the 128-lane floor is the kernels' LANE_BLOCK).  Shedding is a
-    host-side door decision, so the kernel paths must reproduce the jnp
+    """The same proof through the Pallas walk kernel (interpret mode;
+    the 128-lane floor is the kernel's LANE_BLOCK).  Shedding is a
+    host-side door decision, so the kernel path must reproduce the jnp
     survivor stream record-for-record."""
     os.environ[env] = mode
     try:

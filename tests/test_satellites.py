@@ -1,20 +1,11 @@
-"""Regression tests for the ISSUE 1 satellite fixes: columnar-ingestion
-validation and key-code parity (runtime/processor.py), the sharded
-scan-kernel fallback/warning (parallel/sharding.py), and the narrowed
-fused-kernel fallback classification (parallel/batch.py)."""
+"""Regression tests for columnar-ingestion validation and key-code parity
+(runtime/processor.py)."""
 
-import logging
-import os
-
-import jax
 import numpy as np
 import pytest
 
 import engine_scenarios as sc
 from kafkastreams_cep_tpu import Query
-from kafkastreams_cep_tpu.engine import EngineConfig
-from kafkastreams_cep_tpu.parallel import BatchMatcher, ShardedMatcher, key_mesh
-from kafkastreams_cep_tpu.parallel.batch import is_lowering_error
 from kafkastreams_cep_tpu.runtime import CEPProcessor, Record
 
 
@@ -122,104 +113,3 @@ def test_object_keys_out_of_range_int_still_lane_coded():
     )
     assert out == []
     assert proc._lane_of[big] == 0  # assigned; no crash, lane-coded
-
-
-# ---------------------------------------------------------------------------
-# sharding.py — scan-kernel parity with BatchMatcher: warning + fallback
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.skipif(jax.device_count() < 8, reason="needs 8 devices")
-def test_sharded_scan_kernel_infeasible_shard_warns(caplog):
-    cfg = EngineConfig(
-        max_runs=8, slab_entries=16, slab_preds=4, dewey_depth=8, max_walk=8
-    )
-    mesh = key_mesh(jax.devices()[:8])
-    os.environ["CEP_SCAN_KERNEL"] = "1"
-    try:
-        with caplog.at_level(
-            logging.WARNING, logger="kafkastreams_cep_tpu.parallel.sharding"
-        ):
-            m = ShardedMatcher(sc.strict3(), 8, mesh, cfg)  # 1 lane/shard
-    finally:
-        os.environ["CEP_SCAN_KERNEL"] = "0"
-    assert not m.uses_scan_kernel
-    assert any("per-step path" in r.message for r in caplog.records)
-
-
-# ---------------------------------------------------------------------------
-# batch.py — fused-kernel fallback narrowed to lowering errors
-# ---------------------------------------------------------------------------
-
-
-def test_is_lowering_error_classification():
-    assert is_lowering_error(NotImplementedError("no rule"))
-    assert is_lowering_error(RuntimeError("Mosaic failed to compile"))
-    assert is_lowering_error(ValueError("unsupported lowering for op"))
-    # Transient runtime failures must NOT permanently disable the kernel.
-    assert not is_lowering_error(
-        RuntimeError("RESOURCE_EXHAUSTED: out of memory allocating")
-    )
-    assert not is_lowering_error(RuntimeError("operation was CANCELLED"))
-    assert not is_lowering_error(KeyError("some-bug"))
-
-
-def test_fallback_transient_error_keeps_kernel_armed():
-    """A transient first-call failure propagates and the wrapper retries
-    the kernel on the next call instead of permanently downgrading."""
-    cfg = EngineConfig(
-        max_runs=8, slab_entries=16, slab_preds=4, dewey_depth=8, max_walk=8
-    )
-    os.environ["CEP_WALK_KERNEL"] = "0"
-    b = BatchMatcher(sc.strict3(), 4, cfg)
-    calls = {"n": 0}
-
-    def flaky_scan(state, events):
-        calls["n"] += 1
-        raise RuntimeError("RESOURCE_EXHAUSTED: transient")
-
-    b.uses_scan_kernel = True
-    wrapped = b._with_fallback(flaky_scan)
-    for _ in range(2):
-        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
-            wrapped(None, None)
-    assert calls["n"] == 2  # retried the kernel, not the fallback
-    assert b.uses_scan_kernel  # still armed
-
-
-def test_fallback_lowering_error_downgrades_once():
-    """A genuine lowering failure falls back permanently to the per-step
-    path, which must produce the usual results."""
-    import jax.numpy as jnp
-
-    from kafkastreams_cep_tpu.engine import EventBatch
-
-    cfg = EngineConfig(
-        max_runs=8, slab_entries=16, slab_preds=4, dewey_depth=8, max_walk=8
-    )
-    os.environ["CEP_WALK_KERNEL"] = "0"
-    b = BatchMatcher(sc.strict3(), 4, cfg)
-
-    def unlowerable_scan(state, events):
-        raise NotImplementedError("Unsupported lowering: fake Mosaic op")
-
-    b.uses_scan_kernel = True
-    wrapped = b._with_fallback(unlowerable_scan)
-    K, T = 4, 6
-    codes = np.tile(np.array([sc.A, sc.B, sc.C, 0, 0, 0], np.int32), (K, 1))
-    events = EventBatch(
-        key=jnp.zeros((K, T), jnp.int32),
-        value=jnp.asarray(codes),
-        ts=jnp.broadcast_to(
-            1000 + jnp.arange(T, dtype=jnp.int32)[None, :], (K, T)
-        ),
-        off=jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (K, T)),
-        valid=jnp.ones((K, T), bool),
-    )
-    state, out = wrapped(b.init_state(), events)
-    assert not b.uses_scan_kernel  # downgraded
-    ref_state, ref_out = b.scan(b.init_state(), events)
-    np.testing.assert_array_equal(
-        np.asarray(out.count), np.asarray(ref_out.count)
-    )
-    assert int(np.asarray(out.count).sum()) > 0  # the trace really matches

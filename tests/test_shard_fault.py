@@ -24,10 +24,7 @@ import pytest
 import engine_scenarios as sc
 from kafkastreams_cep_tpu.engine import EngineConfig, capacity_counters
 from kafkastreams_cep_tpu.parallel import ShardLost, key_mesh, surviving_mesh
-from kafkastreams_cep_tpu.parallel.batch import (
-    BatchMatcher,
-    guarded_scan_fallback,
-)
+from kafkastreams_cep_tpu.parallel.batch import BatchMatcher
 from kafkastreams_cep_tpu.runtime import (
     CEPProcessor,
     Record,
@@ -178,47 +175,6 @@ def test_surviving_mesh_drops_dead_and_keeps_divisibility():
     assert int(sub2.devices.size) == 2
     with pytest.raises(ValueError):
         surviving_mesh(mesh, range(8), num_lanes=16)
-
-
-# -- the shared lowering-fallback policy (satellite: PR 1 alignment) ---------
-
-
-def test_guarded_fallback_transient_errors_propagate():
-    """A transient device error (RESOURCE_EXHAUSTED, ...) must NOT
-    demote to the slow path — it reaches the supervisor retry instead.
-    Single policy for BatchMatcher and ShardedMatcher
-    (``parallel.batch.guarded_scan_fallback``)."""
-    calls = {"slow": 0}
-
-    def fast(state, events):
-        raise RuntimeError("RESOURCE_EXHAUSTED: hbm oom while allocating")
-
-    guarded = guarded_scan_fallback(
-        fast, lambda: calls.__setitem__("slow", 1) or (lambda s, e: s)
-    )
-    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
-        guarded(1, 2)
-    assert calls["slow"] == 0  # transient: no demotion built
-
-
-def test_guarded_fallback_lowering_error_demotes_once():
-    built = {"n": 0}
-    noted = {"n": 0}
-
-    def fast(state, events):
-        raise NotImplementedError("cannot lower windowed gather")
-
-    def make_slow():
-        built["n"] += 1
-        return lambda state, events: state * events
-
-    guarded = guarded_scan_fallback(
-        fast, make_slow, on_fallback=lambda: noted.__setitem__("n", 1)
-    )
-    assert guarded(3, 2) == 6
-    assert guarded(4, 2) == 8  # sticky: the slow path is reused,
-    assert built["n"] == 1  # built exactly once,
-    assert noted["n"] == 1  # and the demotion was reported.
 
 
 # -- move_lanes: processor-level pure relabeling -----------------------------

@@ -4,8 +4,8 @@ The continuous-profiling contract (ISSUE 6):
 
 1. *Bit-exact across paths*: the per-stage tallies (``stage_counts``) and
    per-stage walk-hop costs (``SlabState.stage_hops``) agree exactly
-   between the jnp engine, the per-step walk kernel, and the whole-scan
-   kernel on a pressured trace.
+   between the jnp engine and the per-step walk kernel on a pressured
+   trace.
 2. *Placement-free*: attribution never changes emissions or any drop
    counter.
 3. *Zero device work when off*: every attribution array has zero size.
@@ -129,29 +129,6 @@ def test_walk_kernel_attribution_parity():
     )
     _attr_equal(st_r, st_k)
     assert int(np.asarray(st_r.slab.stage_hops).sum()) > 0
-
-
-@pytest.mark.slow
-def test_scan_kernel_attribution_parity():
-    # Tier-2 (-m slow, ~12 s interpret): the walk-kernel parity above
-    # keeps kernel attribution in tier-1 (ROADMAP tier-1 budget note,
-    # PR 13).
-    from kafkastreams_cep_tpu.compiler.tables import lower
-    from kafkastreams_cep_tpu.ops.scan_kernel import build_scan
-
-    K, T = 128, 8
-    events = stock_events(K, T, 31)
-    os.environ["CEP_WALK_KERNEL"] = "0"
-    ref = BatchMatcher(stock_demo.stock_pattern(), K, ATTR_CFG)
-    scan = build_scan(lower(stock_demo.stock_pattern()), ATTR_CFG)
-    scan.interpret = True
-    st_r, out_r = ref.scan(ref.init_state(), events)
-    st_k, out_k = scan(ref.init_state(), events)
-    np.testing.assert_array_equal(
-        np.asarray(out_r.count), np.asarray(out_k.count)
-    )
-    _attr_equal(st_r, st_k)
-    assert ref.counters(st_r) == ref.counters(st_k)
 
 
 def test_lazy_drain_hops_are_attributed():

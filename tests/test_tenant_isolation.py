@@ -12,7 +12,7 @@ The contract under test (``compiler/multitenant.py: TenantQuota`` +
 - **Quarantine** circuit-breaks one query out of the bank — its columns
   go dark, its state freezes — and the rest of the bank is bit-identical
   to a bank that never contained it (the differential blast-radius
-  proof, on the jnp path and both Pallas kernels).
+  proof, on the jnp path and the Pallas walk kernel).
 - **Isolated escalation** attributes capacity trips per tenant and
   refuses bank-wide widening charged to an over-quota tenant.
 
@@ -222,14 +222,6 @@ def test_quarantine_blast_radius_walk_kernel(monkeypatch):
     assert _select_walk_kernel(CFG, 2 * 64) == (True, True)
     _assert_quarantine_blast_radius(
         patterns, 0, K=64, T=12, n_batches=2, seed0=5
-    )
-
-
-def test_quarantine_blast_radius_scan_kernel(monkeypatch):
-    monkeypatch.setenv("CEP_WALK_KERNEL", "0")
-    monkeypatch.setenv("CEP_SCAN_KERNEL", "interpret")
-    _assert_quarantine_blast_radius(
-        MIXED[:3], 2, K=4, T=16, n_batches=2, seed0=11
     )
 
 

@@ -5,9 +5,8 @@ The contract (compiler/tiering.py, engine/tiered.py, parallel/tiered.py):
 1. *Bit-identical execution*: for strict-prefix lengths 0, 1, n-1, and n
    (pure stencil), the tiered matcher's matches, emission order, and
    loss counters equal the untiered engine's on loss-free traces —
-   across the jnp path, the fused walk-kernel path, and (untiered side)
-   the whole-scan kernel path, including multi-batch boundaries with
-   ragged valid prefixes.
+   across the jnp path and the fused walk-kernel path, including
+   multi-batch boundaries with ragged valid prefixes.
 2. *Durable carry*: checkpoint/restore and ``widen_state`` preserve a
    live stencil carry — a prefix straddling the snapshot still promotes
    and matches after resume/migration.
@@ -383,74 +382,6 @@ def test_walk_kernel_tiered_parity():
     assert tm.tier_counters(st)["tier_promotions"] > 0
 
 
-@pytest.mark.slow
-def test_scan_kernel_untiered_vs_tiered_parity():
-    """Under CEP_SCAN_KERNEL *both* sides run whole-scan Pallas programs:
-    the untiered engine's, and the native tiered program — the stencil
-    promotion feed joins the event stream, the promotion phase fuses
-    after the engine phases, and every step is gated on device
-    (``build_scan(..., promotion=p)``).  Matches, emission order, and
-    loss counters must still be bit-identical.
-
-    Slow-tier: the interpret-mode whole-scan programs cost ~1 min each on
-    CPU CI; the jnp and walk-kernel differential corpus above stays
-    tier-1 (and the untiered scan kernel is itself pinned bit-identical
-    to the per-step path by tests/test_scan_kernel.py, so tier-1 already
-    covers the composition transitively)."""
-    K, T = 128, 8
-    ev = _kernel_trace(K, T, 9)
-    pat = prefix_n_minus_1()
-    os.environ["CEP_WALK_KERNEL"] = "0"
-    os.environ["CEP_SCAN_KERNEL"] = "interpret"
-    try:
-        b = BatchMatcher(pat, K, KCFG)
-        assert b.uses_scan_kernel
-        tm = TieredBatchMatcher(pat, K, KCFG)
-        assert tm.uses_scan_kernel  # the native tiered program, no fallback
-        sb, ob = b.scan(b.init_state(), ev)
-        st, ot = tm.scan(tm.init_state(), ev)
-    finally:
-        del os.environ["CEP_SCAN_KERNEL"]
-    g = grid(ob)
-    assert g and g == grid(ot)
-    assert b.counters(sb) == tm.counters(st)
-    assert tm.tier_counters(st)["tier_promotions"] > 0
-    # Whole-batch kernel dispatches are host-counted; no chunk gating ran.
-    assert tm.nfa_dispatches == 1 and tm.gate_chunks == 0
-
-
-@pytest.mark.slow
-def test_scan_kernel_tiered_vs_chunked_parity():
-    """The native tiered whole-scan program vs the chunk-gated per-step
-    hybrid path: identical matches, loss counters, and promotion counts
-    across a multi-batch scan (the kernel's per-step gate and fused
-    promotion phase replay the chunked schedule's observable behaviour
-    bit-exactly; dead slab entries may hold different inert pointer
-    garbage between the two slab representations, so raw state equality
-    is deliberately not asserted)."""
-    K, T = 128, 8
-    pat = prefix_n_minus_1()
-    os.environ["CEP_WALK_KERNEL"] = "0"
-    os.environ["CEP_SCAN_KERNEL"] = "interpret"
-    try:
-        tk = TieredBatchMatcher(pat, K, KCFG)
-        assert tk.uses_scan_kernel
-    finally:
-        del os.environ["CEP_SCAN_KERNEL"]
-    tc = TieredBatchMatcher(pat, K, KCFG)
-    assert not tc.uses_scan_kernel
-    sk, sc_ = tk.init_state(), tc.init_state()
-    for seed in (9, 10):
-        ev = _kernel_trace(K, T, seed)
-        sk, ok = tk.scan(sk, ev)
-        sc_, oc = tc.scan(sc_, ev)
-        g = grid(ok)
-        assert g and g == grid(oc)
-    assert tk.counters(sk) == tc.counters(sc_)
-    assert tk.tier_counters(sk) == tc.tier_counters(sc_)
-    assert tk.tier_counters(sk)["tier_promotions"] > 0
-
-
 # ---------------------------------------------------------------------------
 # Lazy extraction under tiering
 # ---------------------------------------------------------------------------
@@ -603,10 +534,9 @@ def test_hybrid_gated_scan_never_syncs_host(monkeypatch):
     pipelined callers keep full dispatch/decode overlap.  Telemetry
     reads do sync, but only when asked, off the scan path."""
     os.environ["CEP_WALK_KERNEL"] = "0"
-    monkeypatch.delenv("CEP_SCAN_KERNEL", raising=False)
     K = 4
     tm = TieredBatchMatcher(sc.skip_till_any(), K, TCFG)
-    assert tm.plan.tier == TIER_HYBRID and not tm.uses_scan_kernel
+    assert tm.plan.tier == TIER_HYBRID
     codes, rng = random_codes(K, 48, seed=7)
     batches = list(ragged_batches(codes, rng, 16))
     st = tm.init_state()
@@ -639,7 +569,6 @@ def test_gate_chunk_size_is_pure_scheduling():
     size yields the untiered engine's exact matches and counters; only
     the gate telemetry differs (ceil(T/C) offered chunks per scan)."""
     os.environ["CEP_WALK_KERNEL"] = "0"
-    os.environ.pop("CEP_SCAN_KERNEL", None)
     K = 6
     pat = sc.skip_till_any()
     # total=24 + per-batch sweeps keep the branchy skip-till-any trace
@@ -656,7 +585,7 @@ def test_gate_chunk_size_is_pure_scheduling():
     assert any(want), "trace must produce matches"
     assert all(ref.counters(sr)[n] == 0 for n in DROP_COUNTERS)
     # One per regime: per-event gating, mid-size (uneven 16/3 tail
-    # chunk), and chunk > batch (whole-scan gate).  Each size is a
+    # chunk), and chunk > batch (one gate per scan).  Each size is a
     # distinct compiled program, so the sweep is priced per entry.
     for chunk in (1, 3, 64):
         tm = TieredBatchMatcher(
@@ -679,7 +608,6 @@ def test_pipelined_tiered_dispatch_never_blocks(monkeypatch):
     in flight while decode pulls batch N-1's outputs.  Phase-tagging the
     sync primitives pins every pull to the decode/gc phases."""
     os.environ["CEP_WALK_KERNEL"] = "0"
-    monkeypatch.delenv("CEP_SCAN_KERNEL", raising=False)
     K = 4
     proc = CEPProcessor(
         sc.skip_till_next(), K, TCFG, epoch=0, pipeline=True

@@ -25,7 +25,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmark"))
 
 import stock_demo
 from kafkastreams_cep_tpu.engine import EngineConfig, EventBatch, TPUMatcher
-from kafkastreams_cep_tpu.ops import scan_kernel
 from kafkastreams_cep_tpu.parallel import ShardedMatcher
 from kafkastreams_cep_tpu.parallel.batch import broadcast_state, kernel_lane_step
 from kafkastreams_cep_tpu.parallel.tiered import TieredBatchMatcher
@@ -105,14 +104,6 @@ def test_walk_kernel_step_compiles(one_chip, features):
     step = jax.jit(kernel_lane_step(matcher._phases))
     _assert_kernel(step.lower(
         _state(matcher, K, one_chip), _events(K, None, one_chip)
-    ))
-
-
-def test_whole_scan_kernel_compiles(one_chip):
-    matcher = TPUMatcher(stock_demo.stock_pattern(), EngineConfig())
-    scan = jax.jit(scan_kernel.build_scan(matcher.tables, matcher.config))
-    _assert_kernel(scan.lower(
-        _state(matcher, K, one_chip), _events(K, 8, one_chip)
     ))
 
 

@@ -14,8 +14,8 @@ The two-tier layout claims (ops/slab.py "Two-tier layout"):
    happens only when the WHOLE slab is full.
 4. *Counter accounting*: every active walk hop is classified exactly once
    (hot_hits + hot_misses = active hops; overflow_walks counts the
-   hot-miss -> overflow-hit subset), and both Pallas kernels agree with
-   the jnp path bit-for-bit.
+   hot-miss -> overflow-hit subset), and the Pallas walk kernel agrees
+   with the jnp path bit-for-bit.
 
 All kernel runs use ``interpret=True`` (CPU CI checks parity, not perf).
 """
@@ -307,32 +307,12 @@ def test_walk_kernel_two_tier_parity_under_pressure():
 
 
 @pytest.mark.slow
-def test_scan_kernel_two_tier_parity_under_pressure():
-    # Tier-2 (-m slow, ~12 s interpret): the walk-kernel variant above
-    # keeps kernel two-tier coverage in tier-1 (ROADMAP tier-1 budget
-    # note, PR 13).
-    from kafkastreams_cep_tpu.compiler.tables import lower
-    from kafkastreams_cep_tpu.ops.scan_kernel import build_scan
-
-    K, T = 128, 12
-    events = stock_events(K, T, 31)
-    os.environ["CEP_WALK_KERNEL"] = "0"
-    ref = BatchMatcher(stock_demo.stock_pattern(), K, PRESSURE_CFG)
-    scan = build_scan(lower(stock_demo.stock_pattern()), PRESSURE_CFG)
-    scan.interpret = True
-    st_r, out_r = ref.scan(ref.init_state(), events)
-    st_k, out_k = scan(ref.init_state(), events)
-    assert_same_run(ref, out_r, st_r, ref, out_k, st_k)
-    assert ref.hot_counters(st_r)["slab_demotions"] > 0
-
-
-@pytest.mark.slow
 def test_two_tier_vs_single_tier_engine_bit_exact():
     """The placement-only claim at engine level: same trace, same shapes,
     hot window on vs off — emissions and drop counters bit-identical.
 
-    Tier-2 (``-m slow``, ~18 s): the walk/scan parity-under-pressure
-    pair above keeps the two-tier claim in tier-1 (ROADMAP tier-1
+    Tier-2 (``-m slow``, ~18 s): the walk-kernel parity under pressure
+    above keeps the two-tier claim in tier-1 (ROADMAP tier-1
     budget note, PR 13)."""
     K, T = 8, 48
     events = stock_events(K, T, 5)

@@ -8,14 +8,23 @@ order, ``NFA.java:102-123``).  The kernel runs in interpreter mode on CPU
 and the engine A/B test.
 """
 
+import logging
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from kafkastreams_cep_tpu import Query
+from kafkastreams_cep_tpu.engine import EngineConfig, EventBatch, StepOutput
 from kafkastreams_cep_tpu.ops import dewey_ops
 from kafkastreams_cep_tpu.ops import slab as slab_mod
 from kafkastreams_cep_tpu.ops.walk_kernel import LANE_BLOCK, walk_pass_kernel
+from kafkastreams_cep_tpu.parallel.batch import (
+    BatchMatcher,
+    _select_walk_kernel,
+)
+from kafkastreams_cep_tpu.runtime.migrate import canonical_state
 
 from test_slab_batched import assert_slab_equal, seed_slab
 
@@ -218,3 +227,150 @@ def test_kernel_put_phase_matches_sequential():
             lane = rep * n_distinct + i
             got = jax.tree_util.tree_map(lambda x: x[lane], new_slab)
             assert_slab_equal(seq[i], got, f"lane={lane}")
+
+
+# ---------------------------------------------------------------------------
+# The whole batched step: BatchMatcher on the walk kernel vs the jnp engine
+# ---------------------------------------------------------------------------
+
+
+def _x_events(xs, ts_mult=1):
+    K_, T = xs.shape
+    steps = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (K_, T))
+    return EventBatch(
+        key=jnp.broadcast_to(
+            jnp.arange(K_, dtype=jnp.int32)[:, None], (K_, T)
+        ),
+        value={"x": jnp.asarray(xs)},
+        ts=steps * ts_mult,
+        off=steps,
+        valid=jnp.ones((K_, T), bool),
+    )
+
+
+def _typed_float_folds():
+    return (
+        Query()
+        .select("a").where(lambda k, v, ts, st: v["x"] > 0)
+        .fold("ema", lambda k, v, curr: 0.5 * curr + 0.25 * v["x"], init=0.0)
+        .fold("n", lambda k, v, curr: curr + 1, init=0)
+        .then()
+        .select("b").skip_till_next_match()
+        .where(lambda k, v, ts, st: (st.get("ema") > 0.7) & (st.get("n") >= 1))
+        .build()
+    )
+
+
+def _windowed_pair():
+    return (
+        Query()
+        .select("a").where(lambda k, v, ts, st: v["x"] == 1)
+        .then()
+        .select("b").skip_till_next_match()
+        .where(lambda k, v, ts, st: v["x"] == 2)
+        .within(5, "ms")
+        .build()
+    )
+
+
+def _strict_chain():
+    return (
+        Query()
+        .select("a").where(lambda k, v, ts, st: v["x"] == 1)
+        .then()
+        .select("b").where(lambda k, v, ts, st: v["x"] == 2)
+        .then()
+        .select("c").where(lambda k, v, ts, st: v["x"] == 3)
+        .build()
+    )
+
+
+_SMALL = dict(
+    max_runs=8, slab_entries=24, slab_preds=4, dewey_depth=8, max_walk=8
+)
+
+
+@pytest.mark.parametrize(
+    "pattern,extra,seed,high,ts_mult",
+    [
+        (_typed_float_folds, {}, 11, 6, 1),
+        (_windowed_pair, {"enforce_windows": True}, 13, 4, 3),
+        (_strict_chain, {}, 17, 5, 1),
+    ],
+    ids=["typed-float-folds", "enforced-5ms-window", "strict-3-chain"],
+)
+def test_walk_kernel_matches_jnp_batch(
+    monkeypatch, pattern, extra, seed, high, ts_mult
+):
+    """``BatchMatcher`` with the walk pass on the kernel (interpret mode)
+    against the all-jnp engine: every ``StepOutput`` field and every
+    engine-state leaf bit-equal after one ``[K, T]`` scan.  States are
+    compared through ``canonical_state``: slots the engine never reads
+    hold implementation-dependent residue."""
+    cfg = EngineConfig(**_SMALL, **extra)
+    rng = np.random.default_rng(seed)
+    xs = rng.integers(0, high, size=(LANE_BLOCK, 14)).astype(np.int32)
+    events = _x_events(xs, ts_mult)
+    runs = {}
+    for mode in ("0", "interpret"):
+        monkeypatch.setenv("CEP_WALK_KERNEL", mode)
+        m = BatchMatcher(pattern(), LANE_BLOCK, cfg)
+        assert m.uses_walk_kernel == (mode == "interpret")
+        runs[mode] = m.scan(m.init_state(), events)
+    (st_r, out_r), (st_k, out_k) = runs["0"], runs["interpret"]
+    assert int(np.asarray(out_r.count).sum()) > 0  # the trace matches
+    for f in StepOutput._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(out_k, f)), np.asarray(getattr(out_r, f)),
+            err_msg=f,
+        )
+    leaves_k = jax.tree_util.tree_leaves_with_path(canonical_state(st_k))
+    leaves_r = jax.tree_util.tree_leaves(canonical_state(st_r))
+    assert len(leaves_k) == len(leaves_r)
+    for (path, a), b in zip(leaves_k, leaves_r):
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b), err_msg=jax.tree_util.keystr(path)
+        )
+
+
+# ---------------------------------------------------------------------------
+# The one device-path decision: _select_walk_kernel
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "mode,backend,lanes,sequential,want,warns",
+    [
+        ("auto", "cpu", LANE_BLOCK, False, (False, False), False),
+        ("auto", "tpu", LANE_BLOCK, False, (True, False), False),
+        ("auto", "tpu", LANE_BLOCK + 8, False, (False, False), False),
+        ("0", "tpu", LANE_BLOCK, False, (False, False), False),
+        ("1", "cpu", LANE_BLOCK, False, (True, False), False),
+        ("1", "tpu", LANE_BLOCK + 8, False, (False, False), True),
+        ("interpret", "cpu", 2 * LANE_BLOCK, False, (True, True), False),
+        ("interpret", "cpu", LANE_BLOCK + 8, False, (False, False), True),
+        ("interpret", "cpu", LANE_BLOCK, True, (False, False), True),
+    ],
+    ids=[
+        "auto-cpu-jnp", "auto-tpu-kernel", "auto-tpu-ragged-jnp",
+        "off-jnp", "forced-kernel", "forced-ragged-jnp-warns",
+        "interpret-kernel", "interpret-ragged-jnp-warns",
+        "interpret-sequential-slab-jnp-warns",
+    ],
+)
+def test_select_walk_kernel(
+    monkeypatch, caplog, mode, backend, lanes, sequential, want, warns
+):
+    """``CEP_WALK_KERNEL`` (``auto`` by default) x backend x lane count x
+    ``sequential_slab`` -> ``(use_kernel, interpret)``.  An explicit
+    request the matcher cannot honour falls back to jnp with a warning;
+    ``auto`` falls back silently."""
+    monkeypatch.setenv("CEP_WALK_KERNEL", mode)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = EngineConfig(sequential_slab=sequential)
+    with caplog.at_level(
+        logging.WARNING, logger="kafkastreams_cep_tpu.parallel.batch"
+    ):
+        assert _select_walk_kernel(cfg, lanes) == want
+    warned = [r for r in caplog.records if "CEP_WALK_KERNEL" in r.message]
+    assert bool(warned) == warns
