@@ -55,6 +55,7 @@ _RUNTIME_KEYS = (
     "gc_carry_pinned",
     "gc_events_materialized",
     "gc_lanes_swept",
+    "pack_lane_misses",
 )
 _SECONDS_KEYS = (
     "pack_seconds",
@@ -66,6 +67,7 @@ _SECONDS_KEYS = (
     "decode_wait_seconds",
     "decode_build_seconds",
     "gc_pull_seconds",
+    "pack_lanes_seconds",
 )
 
 

@@ -63,6 +63,7 @@ from kafkastreams_cep_tpu.utils.logging import get_logger
 logger = get_logger("runtime")
 
 _I32 = np.iinfo(np.int32)
+_I64 = np.iinfo(np.int64)
 
 
 class InputRejected(ValueError):
@@ -276,7 +277,9 @@ class CEPProcessor:
         self.epoch = epoch  # None = rebase to the first record's timestamp
         self.gc_events = gc_events
         self.dedup = dedup
-        self._lane_of: Dict[Hashable, int] = {}
+        # Key -> lane, append-only; assigning the property also drops the
+        # columnar path's sorted index of its integer keys (_int_key_index).
+        self._lane_of = {}
         self._key_of: Dict[int, Hashable] = {}
         self._next_offset = np.zeros(self.num_lanes, dtype=np.int64)
         # Per-lane offset base: the engine sees offsets rebased to log
@@ -295,10 +298,11 @@ class CEPProcessor:
         self.metrics = Metrics()
         # Telemetry (utils/telemetry.py): an optional span sink — every
         # process() call emits one "batch" span with nested phase spans
-        # (pack -> dispatch -> device -> decode -> gc; decode holds
-        # decode_wait and decode_build, gc holds gc_pull); None costs one
-        # attribute check per phase.  ``name`` labels this processor in
-        # per-pattern attribution (bank members pass their query name).
+        # (pack -> dispatch -> device -> decode -> gc; a columnar pack
+        # holds pack_lanes, decode holds decode_wait and decode_build, gc
+        # holds gc_pull); None costs one attribute check per phase.
+        # ``name`` labels this processor in per-pattern attribution (bank
+        # members pass their query name).
         self.trace = trace_sink
         self.name = name or topic
         self._batch_seq = 0
@@ -899,36 +903,8 @@ class CEPProcessor:
                     "schema typed as int"
                 )
 
-        # Lane mapping, committed atomically after the overflow check.
-        if keys_arr.dtype == object:
-            uniq = list(dict.fromkeys(keys_arr.tolist()))
-        else:
-            vals, first = np.unique(keys_arr, return_index=True)
-            uniq = [v.item() for v in vals[np.argsort(first)]]
-        new = [k for k in uniq if k not in self._lane_of]
-        if len(self._lane_of) + len(new) > K:
-            raise InputRejected(
-                f"more than num_lanes={K} distinct keys (first overflowing "
-                f"key: {new[K - len(self._lane_of)]!r}); size the "
-                "processor for the key cardinality it serves"
-            )
-        for k in new:
-            lane = len(self._lane_of)
-            self._lane_of[k] = lane
-            self._key_of[lane] = k
-            logger.info("assigned key %r to lane %d", k, lane)
-        if keys_arr.dtype == object:
-            lanes_arr = np.fromiter(
-                (self._lane_of[k] for k in keys_arr.tolist()),
-                dtype=np.int32, count=n,
-            )
-        else:
-            ku = np.fromiter(self._lane_of.keys(), dtype=keys_arr.dtype)
-            lv = np.fromiter(self._lane_of.values(), dtype=np.int32)
-            order = np.argsort(ku)
-            lanes_arr = lv[order][
-                np.searchsorted(ku[order], keys_arr)
-            ].astype(np.int32)
+        with self._phase("pack_lanes"):
+            lanes_arr = self._column_lanes(keys_arr)
 
         rel = ts_arr - self.epoch
         if rel.size and (rel.min() < _I32.min or rel.max() > _I32.max):
@@ -1020,6 +996,134 @@ class CEPProcessor:
             valid=jnp.asarray(valid),
         )
         return events, rank_of, n
+
+    # -- columnar lane resolution ------------------------------------------
+
+    @property
+    def _lane_of(self) -> Dict[Hashable, int]:
+        return self._lanes
+
+    @_lane_of.setter
+    def _lane_of(self, lanes: Dict[Hashable, int]) -> None:
+        # A replaced map (restore, migration) invalidates the key index; an
+        # in-place write only appends, which the index notices by length.
+        self._lanes = lanes
+        self._key_index = None
+
+    def _int_key_index(self) -> tuple:
+        """``(keys, lanes, n)``: the ``_lane_of`` entries whose key is an
+        int64-range integer, sorted by key, as of a map of ``n`` entries.
+        Rebuilt whenever the map has grown since (lanes are append-only)."""
+        idx = self._key_index
+        n = len(self._lane_of)
+        if idx is None or idx[2] != n:
+            pairs = [
+                (int(k), lane) for k, lane in self._lane_of.items()
+                if isinstance(k, (int, np.integer))
+                and not isinstance(k, bool)
+                and _I64.min <= k <= _I64.max
+            ]
+            keys = np.fromiter(
+                (k for k, _ in pairs), dtype=np.int64, count=len(pairs)
+            )
+            lanes = np.fromiter(
+                (lane for _, lane in pairs), dtype=np.int32, count=len(pairs)
+            )
+            order = np.argsort(keys)
+            idx = self._key_index = (keys[order], lanes[order], n)
+        return idx
+
+    def _assign_lanes(self, uniq: list) -> list:
+        """Give each key of ``uniq`` (distinct, in first-appearance order)
+        that has no lane the next free one, all or none: a batch that would
+        overflow ``num_lanes`` is rejected before any is committed.
+        Returns the newly assigned keys."""
+        K = self.num_lanes
+        new = [k for k in uniq if k not in self._lane_of]
+        if len(self._lane_of) + len(new) > K:
+            raise InputRejected(
+                f"more than num_lanes={K} distinct keys (first overflowing "
+                f"key: {new[K - len(self._lane_of)]!r}); size the "
+                "processor for the key cardinality it serves"
+            )
+        for k in new:
+            lane = len(self._lane_of)
+            self._lane_of[k] = lane
+            self._key_of[lane] = k
+            logger.info("assigned key %r to lane %d", k, lane)
+        return new
+
+    def _column_lanes(self, keys_arr: np.ndarray) -> np.ndarray:
+        """The lane of every row of a key column, new keys assigned in
+        first-appearance order.
+
+        An integer column that fits int64 is looked up in the sorted key
+        index; only the rows it misses (keys new to the processor, or
+        keys the index does not hold, such as a float ``5.0`` equal to an
+        int row's ``5``) take the dict.  Other columns resolve through the
+        dict alone.  ``pack_lane_misses`` counts the rows the index did
+        not answer."""
+        n = keys_arr.shape[0]
+        dt = keys_arr.dtype
+        if not (
+            np.issubdtype(dt, np.signedinteger)
+            or (np.issubdtype(dt, np.unsignedinteger) and dt.itemsize < 8)
+        ):
+            if dt == object:
+                self._assign_lanes(list(dict.fromkeys(keys_arr.tolist())))
+                lanes_arr = np.fromiter(
+                    (self._lane_of[k] for k in keys_arr.tolist()),
+                    dtype=np.int32, count=n,
+                )
+            else:
+                vals, first = np.unique(keys_arr, return_index=True)
+                self._assign_lanes(
+                    [v.item() for v in vals[np.argsort(first)]]
+                )
+                ku = np.fromiter(self._lane_of.keys(), dtype=dt)
+                lv = np.fromiter(self._lane_of.values(), dtype=np.int32)
+                order = np.argsort(ku)
+                lanes_arr = lv[order][
+                    np.searchsorted(ku[order], keys_arr)
+                ].astype(np.int32)
+            self.metrics.pack_lane_misses += n
+            return lanes_arr
+        idx_keys, idx_lanes, _ = self._int_key_index()
+        if idx_keys.size:
+            at = np.searchsorted(idx_keys, keys_arr)
+            np.minimum(at, idx_keys.size - 1, out=at)
+            hit = idx_keys[at] == keys_arr
+            lanes_arr = idx_lanes[at]
+            if hit.all():
+                return lanes_arr
+            rows = np.flatnonzero(~hit)
+        else:
+            lanes_arr = np.empty(n, dtype=np.int32)
+            rows = np.arange(n)
+        vals, first, inv = np.unique(
+            keys_arr[rows], return_index=True, return_inverse=True
+        )
+        new = self._assign_lanes([v.item() for v in vals[np.argsort(first)]])
+        lanes_arr[rows] = np.fromiter(
+            (self._lane_of[k] for k in vals.tolist()),
+            dtype=np.int32, count=vals.size,
+        )[inv]
+        if new:
+            new_keys = np.asarray(new, dtype=np.int64)
+            new_lanes = np.fromiter(
+                (self._lane_of[k] for k in new), dtype=np.int32,
+                count=len(new),
+            )
+            order = np.argsort(new_keys)
+            new_keys, new_lanes = new_keys[order], new_lanes[order]
+            at = np.searchsorted(idx_keys, new_keys)
+            self._key_index = (
+                np.insert(idx_keys, at, new_keys),
+                np.insert(idx_lanes, at, new_lanes),
+                len(self._lane_of),
+            )
+        self.metrics.pack_lane_misses += rows.size
+        return lanes_arr
 
     def _dispatch(self, events, rank_of, n_records, lat=None):
         # Fault-injection sites (utils/failpoints.py; no-ops unless a test

@@ -34,7 +34,9 @@ from kafkastreams_cep_tpu.utils.telemetry import (
 #: prefix (summed over GC passes); ``gc_events_materialized`` the live
 #: rows the GC materialized from lazy column batches, and
 #: ``gc_lanes_swept`` the lanes whose mirror its dead removal visited
-#: (both summed over GC passes).
+#: (both summed over GC passes).  ``pack_lane_misses`` counts the
+#: columnar rows whose key the pack's sorted key index did not hold (new
+#: keys, and every row of a column the index does not serve).
 COUNTER_ATTRS = (
     "records_in",
     "matches_out",
@@ -46,14 +48,17 @@ COUNTER_ATTRS = (
     "gc_carry_pinned",
     "gc_events_materialized",
     "gc_lanes_swept",
+    "pack_lane_misses",
 )
 
 #: Wall-time accumulators; each also feeds the phase histogram of the same
-#: stem ("device_seconds" -> phases["device"]).  The last three are
+#: stem ("device_seconds" -> phases["device"]).  The last four are
 #: sub-phases, timed inside their parent: ``decode_wait`` (the device wait
 #: for the match compaction) and ``decode_build`` (Sequence/Event
 #: construction) inside ``decode``, ``gc_pull`` (the liveness transfer)
-#: inside ``gc``; a parent's self time is its seconds minus its children's.
+#: inside ``gc``, ``pack_lanes`` (the key-to-lane lookup of a columnar
+#: call) inside ``pack``; a parent's self time is its seconds minus its
+#: children's.
 SECONDS_ATTRS = (
     "device_seconds",
     "decode_seconds",
@@ -64,13 +69,14 @@ SECONDS_ATTRS = (
     "decode_wait_seconds",
     "decode_build_seconds",
     "gc_pull_seconds",
+    "pack_lanes_seconds",
 )
 
 #: The batch phases every processor pre-registers, so snapshots of runs
 #: that never hit a phase (e.g. gc off, eager extraction) still carry
 #: identical key sets.
 PHASE_NAMES = ("pack", "dispatch", "drain", "device", "decode", "gc",
-               "decode_wait", "decode_build", "gc_pull")
+               "decode_wait", "decode_build", "gc_pull", "pack_lanes")
 
 
 def _counter_property(name: str) -> property:

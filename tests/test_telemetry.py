@@ -321,7 +321,7 @@ TIMING_KEYS = (
     "device_seconds", "decode_seconds", "pack_seconds", "dispatch_seconds",
     "gc_seconds", "events_per_second_device", "event_time_lag_ms", "hbm",
     "decode_wait_seconds", "decode_build_seconds", "gc_pull_seconds",
-    "phases",
+    "pack_lanes_seconds", "phases",
     # Latency-ledger segment values are wall clock; observation COUNTS are
     # deterministic and asserted separately (tests/test_latency.py).
     "latency",
