@@ -18,7 +18,8 @@ One chip runs, in order:
    device's emissions exactly.
 
 ``--chips 4`` runs only the mesh phase: 16384 lanes sharded over four
-chips against a single-device processor on the same stream, with a
+chips (``mesh=4``, as a configuration file names a mesh) against a
+single-device processor on the same stream, with a
 checkpoint taken on the mesh and restored onto it.
 
 Every failure raises and exits non-zero.  Earlier stdout lines are smoke
@@ -45,7 +46,6 @@ import numpy as np
 import stock_demo
 from kafkastreams_cep_tpu import OracleNFA, native
 from kafkastreams_cep_tpu.engine import EngineConfig
-from kafkastreams_cep_tpu.parallel import key_mesh
 from kafkastreams_cep_tpu.runtime import CEPProcessor
 from kafkastreams_cep_tpu.runtime.checkpoint import (
     restore_processor,
@@ -256,8 +256,9 @@ def phase_oracle(planes, want, seed: int, lanes: int) -> None:
         f"{time.perf_counter() - t0:.3f} s)")
 
 
-def phase_mesh(planes, devs) -> None:
-    mesh = key_mesh(devs[:4])
+def phase_mesh(planes) -> None:
+    # The mesh as a configuration names it: a count of devices.
+    mesh = 4
     single = CEPProcessor(
         stock_demo.stock_pattern(), MESH_LANES, CONFIG, gc_interval=1,
     )
@@ -290,7 +291,7 @@ def main(argv=None) -> int:
     devs = phase_device(args.chips)
     t0 = time.perf_counter()
     if args.chips == 4:
-        phase_mesh(stream(args.seed, MESH_LANES), devs)
+        phase_mesh(stream(args.seed, MESH_LANES))
     else:
         phase_readme()
         planes = stream(args.seed, LANES)

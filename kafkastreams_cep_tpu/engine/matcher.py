@@ -1132,8 +1132,15 @@ def _build_step(tables, cfg: EngineConfig):
         out_off,
         out_count,
         qid=None,
+        slab_gated: bool = False,
     ) -> Tuple[EngineState, StepOutput]:
-        """Queue compaction + padding masking (per lane)."""
+        """Queue compaction + padding masking (per lane).
+
+        ``slab_gated``: the caller's slab pass already left the slab of a
+        padding step untouched (no put or walker enabled there), so the
+        slab skips the padding select: selecting it would keep the step's
+        input slab alive beside its output, a second copy of the largest
+        part of the state."""
         i32 = jnp.int32
         if qid is None:
             qid = jnp.zeros((), i32)
@@ -1283,12 +1290,15 @@ def _build_step(tables, cfg: EngineConfig):
         )
 
         # Padding steps leave the state untouched and emit nothing.
+        new_slab = new_state.slab
         new_state = jax.tree_util.tree_map(
             lambda n, o: jnp.where(
                 jnp.reshape(valid, (1,) * n.ndim), n, o
             ) if n.ndim else jnp.where(valid, n, o),
             new_state, state,
         )
+        if slab_gated:
+            new_state = new_state._replace(slab=new_slab)
         # The step counter ticks on every step, padding included — it is
         # the StepOutput ``t`` index (handle ordering), not match state.
         new_state = new_state._replace(step_seq=state.step_seq + 1)

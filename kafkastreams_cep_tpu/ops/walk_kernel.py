@@ -55,7 +55,7 @@ def _cumsum0(x):
 
 def _coalesced_demote(
     refs, p_en, p_first, p_cur, p_pst, p_pof, off_l,
-    EHk: int, EO: int, MP: int, D: int,
+    EHk: int, EO: int, MP: int,
 ):
     """One pass serving ALL of a step's hot→overflow demotions, plus the
     per-creation hot-slot claim map the put loop allocates from —
@@ -80,7 +80,7 @@ def _coalesced_demote(
     ``tests/test_two_tier.py``.
 
     ``refs`` is ``(stage, off, refs, npreds, pstage, poff, pvlen, pver,
-    dm)`` output refs (pver laid out ``[D, E, MP, L]``); ``p_*`` are the
+    dm)`` output refs (pver laid out ``[E, MP, D, L]``); ``p_*`` are the
     step's put-op planes ``[PP, L]`` (values, lane-last).  Returns
     ``(creator [PP, L] bool, crank [PP, L], claim [EHk, L], k_cap
     [1, L])``: creation-rank ``c`` allocates the slot with ``claim == c``
@@ -175,12 +175,12 @@ def _coalesced_demote(
         mv3(o_pstage)
         mv3(o_poff)
         mv3(o_pvlen)
-        for d in range(D):
+        for m in range(MP):
             v = jnp.sum(
-                jnp.where(mv[:, :, None, :], o_pver[d, 0:EHk][:, None], 0),
+                jnp.where(mv[:, :, None, :], o_pver[0:EHk, m][:, None], 0),
                 axis=0,
-            )
-            o_pver[d, EHk:] = jnp.where(anym[:, None, :], v, o_pver[d, EHk:])
+            )  # [EO, D, L]
+            o_pver[EHk:, m] = jnp.where(anym[:, None, :], v, o_pver[EHk:, m])
         vst = jnp.sum(jnp.where(mv, o_stage[0:EHk][:, None, :], 0), axis=0)
         vof = jnp.sum(jnp.where(mv, o_off[0:EHk][:, None, :], 0), axis=0)
         o_stage[EHk:] = jnp.where(anym, vst, o_stage[EHk:])
@@ -225,11 +225,11 @@ def _kernel(
     st_stage, st_off = rest[i], rest[i + 1]
     tier_scratch = rest[i + 2:]
     E, MP, L = pstage.shape
-    # pver blocks arrive [D, E, MP, L]: the tiled trailing dims are then
-    # (MP=8-aligned, L) instead of (D, L) with D padded up to the sublane
-    # tile — ~25% less VMEM traffic on the per-hop pointer-row reduce,
-    # the kernel's dominant op.
-    D = pver.shape[0]
+    # pver blocks arrive [E, MP, D, L], the order in which the device keeps
+    # the state's [K, E, MP, D] (lanes minor): the slab then reaches the
+    # kernel, and returns from it, with no relayout copy of the state's
+    # largest array.  The tiled trailing dims are (D, L).
+    D = pver.shape[2]
     PW = en.shape[0]
     OR = out_rows
     i32 = jnp.int32
@@ -274,7 +274,7 @@ def _kernel(
     iota_mp = jax.lax.broadcasted_iota(i32, (MP, L), 0)
     iota_mp3 = jax.lax.broadcasted_iota(i32, (E, MP, L), 1)
     iota_mp3h = jax.lax.broadcasted_iota(i32, (EHk, MP, L), 1)
-    iota_d3 = jax.lax.broadcasted_iota(i32, (D, MP, L), 0)
+    iota_d3 = jax.lax.broadcasted_iota(i32, (MP, D, L), 1)
     iota_or3 = jax.lax.broadcasted_iota(i32, (OR, W, L), 0)
     iota_w2 = jax.lax.broadcasted_iota(i32, (W, L), 0)
     iota_or2 = jax.lax.broadcasted_iota(i32, (OR, L), 0)
@@ -297,7 +297,7 @@ def _kernel(
                  o_pvlen, o_pver, o_dm),
                 p_rank[:] >= 0, p_first[:] != 0, p_cur[:],
                 p_pstage[:], p_poff[:], ev_off[:],
-                EHk=EHk, EO=EO, MP=MP, D=D,
+                EHk=EHk, EO=EO, MP=MP,
             )
 
         def put_body(b):
@@ -390,7 +390,7 @@ def _kernel(
             )
             o_pvlen[:] = jnp.where(m2, pvl[:, None, :], o_pvlen[:])
             o_pver[:] = jnp.where(
-                m2[None], pvr[:, None, None, :], o_pver[:]
+                m2[:, :, None, :], pvr[None, None], o_pver[:]
             )
             o_npreds[:] = jnp.where(
                 m1, n_eff + jnp.where(do, 1, 0), o_npreds[:]
@@ -418,7 +418,7 @@ def _kernel(
         wrm = jnp.any(selm & (wrem[:] != 0), axis=0, keepdims=True)
         wot = jnp.any(selm & (wout[:] != 0), axis=0, keepdims=True)
         srow = pick(iota_pw - out_base)
-        # wver arrives [D, PW, L] (same tile-exact layout as pver).
+        # wver arrives [D, PW, L] (tile-exact trailing dims).
         qv0 = jnp.sum(
             jnp.where(selm[None, :, :], wver[:], 0), axis=1
         )  # [D, L]
@@ -459,7 +459,7 @@ def _kernel(
                 sc_ps[:] = jnp.zeros((MP, L), i32)
                 sc_po[:] = jnp.zeros((MP, L), i32)
                 sc_pl[:] = jnp.zeros((MP, L), i32)
-                sc_pv[:] = jnp.zeros((D, MP, L), i32)
+                sc_pv[:] = jnp.zeros((MP, D, L), i32)
 
                 @pl.when(jnp.any(miss))
                 def _():
@@ -488,9 +488,9 @@ def _kernel(
                     )
                     sc_pv[:] = jnp.sum(
                         jnp.where(
-                            hamo[None, :, None, :], o_pver[:, EHk:], 0
+                            hamo[:, None, None, :], o_pver[EHk:], 0
                         ),
-                        axis=1,
+                        axis=0,
                     )
 
                 act_o = sc_found[:] != 0  # active walkers resolved overflow
@@ -542,9 +542,9 @@ def _kernel(
             po_ = jnp.sum(jnp.where(ham3, o_poff[0:EHk], 0), axis=0)
             pl_ = jnp.sum(jnp.where(ham3, o_pvlen[0:EHk], 0), axis=0)
             pv_ = jnp.sum(
-                jnp.where(ham_h[None, :, None, :], o_pver[:, 0:EHk], 0),
-                axis=1,
-            )  # [D, MP, L]
+                jnp.where(ham_h[:, None, None, :], o_pver[0:EHk], 0),
+                axis=0,
+            )  # [MP, D, L]
             if EO:
                 ps_ = ps_ + sc_ps[:]
                 po_ = po_ + sc_po[:]
@@ -555,19 +555,19 @@ def _kernel(
             # dewey_ops.is_compatible vectorized over the MP pointers
             # (DeweyVersion.java:62-82).  Prefix checks count violations in
             # i32 — Mosaic cannot select on i1 vectors.
-            neq = (qv[:, None, :] != pv_).astype(jnp.int32)  # [D, MP, L]
-            plm = pl_[None, :, :]
+            neq = (qv[None] != pv_).astype(jnp.int32)  # [MP, D, L]
+            plm = pl_[:, None, :]
             prefix_full = (
-                jnp.sum(neq * (iota_d3 < plm).astype(jnp.int32), axis=0) == 0
+                jnp.sum(neq * (iota_d3 < plm).astype(jnp.int32), axis=1) == 0
             )
             prefix_butl = (
-                jnp.sum(neq * (iota_d3 < plm - 1).astype(jnp.int32), axis=0)
+                jnp.sum(neq * (iota_d3 < plm - 1).astype(jnp.int32), axis=1)
                 == 0
             )
             last_q = jnp.sum(
-                jnp.where(iota_d3 == plm - 1, qv[:, None, :], 0), axis=0
+                jnp.where(iota_d3 == plm - 1, qv[None], 0), axis=1
             )
-            last_p = jnp.sum(jnp.where(iota_d3 == plm - 1, pv_, 0), axis=0)
+            last_p = jnp.sum(jnp.where(iota_d3 == plm - 1, pv_, 0), axis=1)
             ok = ((ql > pl_) & prefix_full) | (
                 (ql == pl_) & prefix_butl & (last_q >= last_p)
             )
@@ -610,9 +610,9 @@ def _kernel(
                 shift(lambda: o_pvlen[0:EHk], set_h(o_pvlen), pm)
 
                 def put_pver(v):
-                    o_pver[:, 0:EHk] = v
+                    o_pver[0:EHk] = v
 
-                shift(lambda: o_pver[:, 0:EHk], put_pver, pm[None], axis=2)
+                shift(lambda: o_pver[0:EHk], put_pver, pm[:, :, None, :])
                 o_npreds[0:EHk] = o_npreds[0:EHk] - jnp.where(
                     ham_h & prune_h, 1, 0
                 )
@@ -659,11 +659,11 @@ def _kernel(
                     shift_o(lambda: o_pvlen[EHk:], set_o(o_pvlen), pmo)
 
                     def put_pver_o(v):
-                        o_pver[:, EHk:] = v
+                        o_pver[EHk:] = v
 
                     shift_o(
-                        lambda: o_pver[:, EHk:], put_pver_o, pmo[None],
-                        axis=2,
+                        lambda: o_pver[EHk:], put_pver_o,
+                        pmo[:, :, None, :],
                     )
                     o_npreds[EHk:] = o_npreds[EHk:] - jnp.where(
                         hamo & prune_o, 1, 0
@@ -672,7 +672,7 @@ def _kernel(
             nxt_s = jnp.sum(jnp.where(ohj, ps_, 0), axis=0, keepdims=True)
             nxt_o = jnp.sum(jnp.where(ohj, po_, 0), axis=0, keepdims=True)
             nxt_l = jnp.sum(jnp.where(ohj, pl_, 0), axis=0, keepdims=True)
-            nxt_v = jnp.sum(jnp.where(ohj[None], pv_, 0), axis=1)  # [D, L]
+            nxt_v = jnp.sum(jnp.where(ohj[:, None, :], pv_, 0), axis=0)  # [D, L]
 
             nactive = active & selany & (nxt_s >= 0)
             # Extraction walkers get W emitting hops; cut beyond that is a
@@ -816,8 +816,7 @@ def walk_pass_kernel(
         tin(slab.pstage),
         tin(slab.poff),
         tin(slab.pvlen),
-        # [K, E, MP, D] -> [D, E, MP, K]: tile-exact (MP, L) trailing dims.
-        jnp.transpose(slab.pver, (3, 1, 2, 0)),
+        tin(slab.pver),  # [E, MP, D, K]
         # Per-lane scalar counters arrive as [K]; kernel blocks want [1, L].
         row(slab.missing),
         row(slab.trunc),
@@ -868,7 +867,7 @@ def walk_pass_kernel(
         jax.ShapeDtypeStruct((E, MP, K), i32),  # pstage
         jax.ShapeDtypeStruct((E, MP, K), i32),  # poff
         jax.ShapeDtypeStruct((E, MP, K), i32),  # pvlen
-        jax.ShapeDtypeStruct((D, E, MP, K), i32),  # pver
+        jax.ShapeDtypeStruct((E, MP, D, K), i32),  # pver
         jax.ShapeDtypeStruct((1, K), i32),  # missing
         jax.ShapeDtypeStruct((1, K), i32),  # trunc
         jax.ShapeDtypeStruct((1, K), i32),  # full_drops
@@ -902,8 +901,16 @@ def walk_pass_kernel(
             pltpu.VMEM((MP, LANE_BLOCK), jnp.int32),  # sc_ps
             pltpu.VMEM((MP, LANE_BLOCK), jnp.int32),  # sc_po
             pltpu.VMEM((MP, LANE_BLOCK), jnp.int32),  # sc_pl
-            pltpu.VMEM((D, MP, LANE_BLOCK), jnp.int32),  # sc_pv
+            pltpu.VMEM((MP, D, LANE_BLOCK), jnp.int32),  # sc_pv
         ]
+
+    # The slab is updated in place: each of its 19 leaves (and the stage
+    # hops) is written over its own input.  A lane block is read whole
+    # before its block is written, so no block reads a neighbour's result,
+    # and the step holds one copy of the slab where it held two.
+    aliases = {i: i for i in range(19)}
+    if SA:
+        aliases[len(ins) - 1] = 22
 
     outs = pl.pallas_call(
         functools.partial(
@@ -914,6 +921,7 @@ def walk_pass_kernel(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
@@ -934,7 +942,7 @@ def walk_pass_kernel(
         pstage=tout(n_pstage),
         poff=tout(n_poff),
         pvlen=tout(n_pvlen),
-        pver=jnp.transpose(n_pver, (3, 1, 2, 0)),
+        pver=tout(n_pver),
         missing=unrow(n_missing),
         trunc=unrow(n_trunc),
         full_drops=unrow(n_fulld),

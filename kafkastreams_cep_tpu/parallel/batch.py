@@ -82,8 +82,14 @@ def kernel_lane_step(phases, interpret: bool = False, qids=None):
         else:
             # Stacked bank: each lane evaluates its own query's tables.
             rec = jax.vmap(ph.eval_chain)(state, ev, qids)
+        # A padding step enables no put and no walker, so the kernel leaves
+        # its slab as it was and ``finish`` need not select the old slab
+        # back: the kernel then updates the slab in place.
+        valid = ev.valid.astype(bool)
         ops = jax.vmap(ph.build_puts)(state, rec, ev)
+        ops = ops._replace(en=ops.en & valid[:, None])
         wk = jax.vmap(ph.build_walkers)(state, rec, ev)
+        wk = (wk[0] & valid[:, None],) + tuple(wk[1:])
         # Both slab phases (consuming puts, then all walks) run inside one
         # Pallas call: the slab crosses HBM once per step instead of twice.
         # (Lane-load sorting was tried here and measured net-negative: in
@@ -96,11 +102,12 @@ def kernel_lane_step(phases, interpret: bool = False, qids=None):
             put_ops=ops, ev_off=ev.off,
             hot_entries=ph.hot_entries,
         )
+        finish = functools.partial(ph.finish, slab_gated=True)
         if qids is None:
-            return jax.vmap(ph.finish)(
+            return jax.vmap(finish)(
                 state, ev, rec, slab, out_stage, out_off, out_count
             )
-        return jax.vmap(ph.finish)(
+        return jax.vmap(finish)(
             state, ev, rec, slab, out_stage, out_off, out_count, qids
         )
 
@@ -141,6 +148,14 @@ def sweep_lanes(state: EngineState, depth: int, do_renorm: bool) -> EngineState:
     from kafkastreams_cep_tpu.ops import renorm as renorm_mod
     from kafkastreams_cep_tpu.ops import slab as slab_mod
 
+    blocks = _sweep_blocks(state)
+    if blocks > 1:
+        return _by_lane_blocks(
+            functools.partial(
+                sweep_lanes, depth=depth, do_renorm=do_renorm
+            ),
+            state, blocks,
+        )
     HB = state.hr_stage.shape[-1]
     R = state.alive.shape[-1]
     pending = (
@@ -179,6 +194,48 @@ def sweep_lanes(state: EngineState, depth: int, do_renorm: bool) -> EngineState:
     return state
 
 
+#: The sweep's temporaries are one to two times the state it sweeps.  A
+#: state of more than twice this many bytes sweeps lane block by lane
+#: block, each of at most this many bytes, written back in place: 131,072
+#: stock lanes (5.5e9 B a chip) then sweep in four blocks beside their
+#: state and a call's match grid on one 16 GiB chip.  Smaller states (every
+#: one-chip cell's) sweep whole, as one pass.
+SWEEP_BLOCK_BYTES = 2 * 2**30
+
+
+def _sweep_blocks(state: EngineState) -> int:
+    """The number of lane blocks the sweep of ``state`` runs in: 1 up to
+    ``2 * SWEEP_BLOCK_BYTES`` of state, else the fewest, a power of two
+    dividing the lanes, of at most :data:`SWEEP_BLOCK_BYTES` each."""
+    leaves = jax.tree_util.tree_leaves(state)
+    K = int(leaves[0].shape[0])
+    size = sum(int(x.size) * x.dtype.itemsize for x in leaves)
+    if size <= 2 * SWEEP_BLOCK_BYTES:
+        return 1
+    n = 1
+    while size > n * SWEEP_BLOCK_BYTES and K % (2 * n) == 0:
+        n *= 2
+    return n
+
+
+def _by_lane_blocks(fn, state, blocks: int):
+    """``fn`` (lane-local) over ``blocks`` equal lane blocks of ``state``, one
+    after another, each block's result written over its own lanes."""
+    B = int(jax.tree_util.tree_leaves(state)[0].shape[0]) // blocks
+
+    def body(i, st):
+        at = i * B
+        blk = jax.tree_util.tree_map(
+            lambda x: jax.lax.dynamic_slice_in_dim(x, at, B), st
+        )
+        return jax.tree_util.tree_map(
+            lambda x, b: jax.lax.dynamic_update_slice_in_dim(x, b, at, 0),
+            st, fn(blk),
+        )
+
+    return jax.lax.fori_loop(0, blocks, body, state)
+
+
 def _select_walk_kernel(config: EngineConfig, num_lanes: int):
     """Decide (use_kernel, interpret) for this batch shape.
 
@@ -215,6 +272,10 @@ class BatchMatcher:
     ``[K, ...]``); ``scan`` consumes a ``[K, T]`` time-stacked batch and runs
     the whole window in a single ``lax.scan`` dispatch — the shape the
     micro-batcher (``runtime/processor.py``) and the benchmarks feed.
+
+    ``scan``, ``sweep`` and ``drain`` take the state donated: its buffers
+    are reused for the state they return, and the state passed in is
+    deleted, so a call holds one copy of the state, not two.
     """
 
     def __init__(
@@ -263,7 +324,8 @@ class BatchMatcher:
             "batch.step", self._mode_tag, lambda: jax.jit(self._step_fn)
         )
         self.scan = self._cached(
-            "batch.scan", self._mode_tag, lambda: jax.jit(self._scan_fn)
+            "batch.scan", self._mode_tag,
+            lambda: jax.jit(self._scan_fn, donate_argnums=0),
         )
         # Measured per-conjunct selectivity: under stage_attribution every
         # consuming-edge conjunct is tallied unconditionally over each
@@ -331,7 +393,8 @@ class BatchMatcher:
         return tracecache.lookup(
             "batch.sweep", (depth, do_renorm),
             lambda: jax.jit(
-                lambda state: sweep_lanes(state, depth, do_renorm)
+                lambda state: sweep_lanes(state, depth, do_renorm),
+                donate_argnums=0,
             ),
         )
 
@@ -358,7 +421,7 @@ class BatchMatcher:
             drain_fn = self.matcher._drain_fn
             return tracecache.lookup(
                 "batch.drain", dkey,
-                lambda: jax.jit(jax.vmap(drain_fn)),
+                lambda: jax.jit(jax.vmap(drain_fn), donate_argnums=0),
             )
         from kafkastreams_cep_tpu.ops.walk_kernel import walk_pass_kernel
 
@@ -413,7 +476,7 @@ class BatchMatcher:
             return state, out
 
         return tracecache.lookup(
-            "batch.drain", dkey, lambda: jax.jit(drain)
+            "batch.drain", dkey, lambda: jax.jit(drain, donate_argnums=0)
         )
 
     def counters(self, state: EngineState) -> Dict[str, int]:

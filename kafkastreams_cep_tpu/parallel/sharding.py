@@ -91,6 +91,23 @@ def key_mesh(devices: Optional[Sequence] = None, axis: str = "keys") -> Mesh:
     return Mesh(np.array(devs), (axis,))
 
 
+def as_mesh(mesh):
+    """``mesh`` as a :class:`Mesh`: a whole number ``n`` is
+    :func:`key_mesh` over the first ``n`` devices (so a configuration file
+    can name a mesh); a ``Mesh`` or ``None`` is returned as it is."""
+    import numpy as np
+
+    if isinstance(mesh, bool) or not isinstance(mesh, (int, np.integer)):
+        return mesh
+    devices = jax.devices()
+    if not 1 <= mesh <= len(devices):
+        raise ValueError(
+            f"mesh={mesh}: a mesh of {mesh} devices, but {len(devices)} "
+            "are visible"
+        )
+    return key_mesh(devices[:mesh])
+
+
 class ShardedMatcher:
     """``K`` key lanes sharded over a device mesh via ``jax.shard_map``.
 
@@ -98,6 +115,15 @@ class ShardedMatcher:
     lanes with the same compiled per-lane program as :class:`BatchMatcher`.
     ``stats`` is the one collective op — a ``psum`` of the overflow counters
     and per-step match counts across shards.
+
+    The scan, the sweep and the drain take the state donated (its buffers
+    are reused for the result, and the caller's state is deleted) and
+    return it, and take the events, laid out as :meth:`init_state` and
+    :meth:`shard_events` lay them: one compiled program per call shape,
+    whatever produced its arguments.  They compile as ``jit_sharded_scan``,
+    ``jit_sharded_sweep`` and ``jit_sharded_drain`` (the decode's
+    per-shard compaction as ``jit_sharded_compact``), so a device trace
+    tells them from the one-chip programs.
     """
 
     def __init__(
@@ -117,6 +143,8 @@ class ShardedMatcher:
             )
         self.num_lanes = int(num_lanes)
         spec = P(self.axis)
+        self._spread = NamedSharding(mesh, spec)
+        self._compact_jits = {}
         # Each shard steps K/n lanes with the same code as BatchMatcher —
         # including the fused walk kernel when the per-shard lane count
         # allows it (Pallas composes with shard_map; lanes never cross
@@ -148,20 +176,83 @@ class ShardedMatcher:
             f, mesh=mesh, in_specs=spec, out_specs=out_specs, check_vma=False
         )
         self.step = jax.jit(shard(local_step, spec))
-        self.scan = jax.jit(shard(local_scan, spec))
+        scan = shard(local_scan, spec)
+
+        def sharded_scan(state, events):
+            return scan(state, events)
+
+        self.scan = self._donating(sharded_scan, 2)
         self._stats = jax.jit(shard(local_stats, P()))
+
+    def _donating(self, fn, n_args: int):
+        """``fn`` jitted with its first argument (the state) donated and
+        every argument and result laid out as ``NamedSharding(mesh,
+        P(axis))``, so a call's results are laid out exactly as the next
+        call's arguments and the compiled program is found again."""
+        return jax.jit(
+            fn, donate_argnums=0,
+            in_shardings=(self._spread,) * n_args,
+            out_shardings=self._spread,
+        )
 
     @property
     def names(self):
         return self.matcher.names
 
     def init_state(self) -> EngineState:
-        state = broadcast_state(self.matcher.init_state(), self.num_lanes)
-        return jax.device_put(state, NamedSharding(self.mesh, P(self.axis)))
+        """The initial state, built on each device as its own lane block
+        (never whole on one device: at a chip's full key share the state
+        is larger than one chip)."""
+        return jax.jit(
+            lambda: broadcast_state(self.matcher.init_state(), self.num_lanes),
+            out_shardings=self._spread,
+        )()
 
     def shard_events(self, events: EventBatch) -> EventBatch:
-        """Place a host-built ``[K, ...]`` event batch onto the mesh."""
-        return jax.device_put(events, NamedSharding(self.mesh, P(self.axis)))
+        """Place a ``[K, ...]`` event batch onto the mesh: host arrays go
+        straight to each device's block in one transfer."""
+        return jax.device_put(events, self._spread)
+
+    def compact_matches(self, out, budget: int):
+        """Each shard's hit rows of a scan's ``StepOutput``, compacted on
+        its own device into ``budget`` rows (``ops/decode.py``
+        ``compact_matches`` over its lane block): ``(stage [n*G, W], off
+        [n*G, W], count [n*G], k [n*G], t [n*G], r [n*G], hits [n])``,
+        shard ``s``'s rows at ``[s*G, s*G + hits[s])``, ``k`` the global
+        lane.  Lane-local, so no grid crosses between chips (a global
+        prefix sum over the sharded grid would gather it)."""
+        budget = int(budget)
+        fn = self._compact_jits.get(budget)
+        if fn is None:
+            fn = self._compact_jits[budget] = self._build_compact(budget)
+        return fn(out)
+
+    def _build_compact(self, budget: int):
+        from kafkastreams_cep_tpu.ops.decode import compact_matches
+
+        per = self.num_lanes // int(self.mesh.devices.size)
+        axis = self.axis
+
+        def local(out):
+            stage, off, count, k, t, r, hits, _ = compact_matches(
+                out, budget
+            )
+            k = k + jax.lax.axis_index(axis) * per
+            return stage, off, count, k, t, r, hits.reshape(1)
+
+        spec = P(axis)
+        local = jax.shard_map(
+            local, mesh=self.mesh, in_specs=spec, out_specs=spec,
+            check_vma=False,
+        )
+
+        def sharded_compact(out):
+            return local(out)
+
+        return jax.jit(
+            sharded_compact, in_shardings=self._spread,
+            out_shardings=self._spread,
+        )
 
     def stats(self, state: EngineState) -> Dict[str, int]:
         """Mesh-global counter totals (one ``psum`` across all shards)."""
@@ -197,14 +288,16 @@ class ShardedMatcher:
 
     @functools.cached_property
     def _drain_jit(self):
-        local = jax.vmap(self.matcher._drain_fn)
         spec = P(self.axis)
-        return jax.jit(
-            jax.shard_map(
-                local, mesh=self.mesh, in_specs=spec,
-                out_specs=(spec, spec), check_vma=False,
-            )
+        local = jax.shard_map(
+            jax.vmap(self.matcher._drain_fn), mesh=self.mesh, in_specs=spec,
+            out_specs=(spec, spec), check_vma=False,
         )
+
+        def sharded_drain(state):
+            return local(state)
+
+        return self._donating(sharded_drain, 1)
 
     @functools.cached_property
     def _stage_stats(self):
@@ -317,9 +410,12 @@ class ShardedMatcher:
             return sweep_lanes(state, depth, do_renorm)
 
         spec = P(self.axis)
-        return jax.jit(
-            jax.shard_map(
-                local, mesh=self.mesh, in_specs=spec, out_specs=spec,
-                check_vma=False,
-            )
+        local = jax.shard_map(
+            local, mesh=self.mesh, in_specs=spec, out_specs=spec,
+            check_vma=False,
         )
+
+        def sharded_sweep(state):
+            return local(state)
+
+        return self._donating(sharded_sweep, 1)

@@ -311,10 +311,12 @@ def choose_bank(
     serial_states = [m.init_state() for m in serial_ms]
 
     def run_serial():
+        # Each scan consumes its state: carry the result to the next run.
         outs = [
             m.scan(s, sample_events)
             for m, s in zip(serial_ms, serial_states)
         ]
+        serial_states[:] = [o[0] for o in outs]
         jax.block_until_ready([o[1].count for o in outs])
 
     t_serial = best_of(run_serial)

@@ -320,7 +320,7 @@ class TieredBatchMatcher:
                 self.plan.prefix_len, self.inner.uses_walk_kernel,
                 self.inner._kernel_interpret,
             ),
-            lambda: jax.jit(tiered_suffix_scan),
+            lambda: jax.jit(tiered_suffix_scan, donate_argnums=0),
         )
 
     @property

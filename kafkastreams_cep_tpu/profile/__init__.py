@@ -72,16 +72,19 @@ def _stock_events(K: int, T: int, seed: int = 42):
     )
 
 
-def _timed_scan(batch, state0, events, reps: int):
-    """(best seconds, compile seconds) of ``batch.scan`` on ``events``."""
+def _timed_scan(batch, events, reps: int):
+    """(best seconds, compile seconds, state) of ``batch.scan`` on
+    ``events``, each run from a fresh initial state (the scan consumes the
+    state it is given)."""
     import jax
 
     t0 = time.perf_counter()
-    state, out = batch.scan(state0, events)
+    state, out = batch.scan(batch.init_state(), events)
     jax.block_until_ready(out.count)
     compile_s = time.perf_counter() - t0
     best = float("inf")
     for _ in range(max(reps, 1)):
+        state0 = jax.block_until_ready(batch.init_state())
         t0 = time.perf_counter()
         state, out = batch.scan(state0, events)
         jax.block_until_ready(out.count)
@@ -113,14 +116,10 @@ def run_selectivity(args) -> Dict[str, Any]:
     events = _stock_events(K, T, seed=args.seed)
 
     off_b = BatchMatcher(pattern, K, base)
-    best_off, comp_off, _ = _timed_scan(
-        off_b, off_b.init_state(), events, args.reps
-    )
+    best_off, comp_off, _ = _timed_scan(off_b, events, args.reps)
     on_cfg = dataclasses.replace(base, stage_attribution=True)
     on_b = BatchMatcher(pattern, K, on_cfg)
-    best_on, comp_on, state = _timed_scan(
-        on_b, on_b.init_state(), events, args.reps
-    )
+    best_on, comp_on, state = _timed_scan(on_b, events, args.reps)
     overhead = (best_on - best_off) / best_off * 100.0
 
     # Per-query compiler-tiering tag (ISSUE 7): which tier this query

@@ -240,7 +240,8 @@ def restore_processor(
     the mesh (or single device) that wrote the snapshot — the rebalance
     analog: lanes re-place onto the new device set, exactly like Kafka
     Streams restoring changelogged partitions onto a resized consumer
-    group.  The mesh size must divide ``num_lanes`` (refused with a clear
+    group.  ``mesh`` may be a device count, as for :class:`CEPProcessor`.
+    The mesh size must divide ``num_lanes`` (refused with a clear
     error, not a shard_map internality); a device-count change routes the
     state through ``runtime.migrate.repartition_state`` — identity, by
     the relabeling invariant below — and is logged as an explicit
@@ -250,6 +251,9 @@ def restore_processor(
         ckpt = load_checkpoint(path)
     header = ckpt["header"]
     config = EngineConfig(**header["config"])
+    from kafkastreams_cep_tpu.parallel.sharding import as_mesh
+
+    mesh = as_mesh(mesh)
     target_devs = int(mesh.devices.size) if mesh is not None else 1
     if int(header["num_lanes"]) % target_devs:
         raise ValueError(

@@ -56,6 +56,8 @@ _RUNTIME_KEYS = (
     "gc_events_materialized",
     "gc_lanes_swept",
     "pack_lane_misses",
+    "shard_matches_max",
+    "shard_matches_min",
 )
 _SECONDS_KEYS = (
     "pack_seconds",
@@ -68,6 +70,7 @@ _SECONDS_KEYS = (
     "decode_build_seconds",
     "gc_pull_seconds",
     "pack_lanes_seconds",
+    "shard_put_seconds",
 )
 
 

@@ -204,7 +204,13 @@ class CEPProcessor:
         # each lane's run queue/slab/folds live on exactly one device for
         # the processor's lifetime).  The rest of the runtime is identical:
         # checkpoints gather to host arrays (mesh-agnostic, so a restore
-        # may re-place onto a different mesh — the rebalance analog).
+        # may re-place onto a different mesh — the rebalance analog).  A
+        # whole number ``n`` is the mesh of the first ``n`` devices
+        # (``parallel.sharding.as_mesh``), so a configuration file can
+        # name one.
+        from kafkastreams_cep_tpu.parallel.sharding import as_mesh
+
+        mesh = as_mesh(mesh)
         self.mesh = mesh
         tiering = config is not None and getattr(config, "tiering", False)
         if mesh is not None:
@@ -792,14 +798,8 @@ class CEPProcessor:
             col = np.asarray([leaves[i] for leaves in batch_leaves], dtype=dt)
             native.pack_column(val_leaves[i], col, lanes_arr, pos, keep)
 
-        events = EventBatch(
-            key=jnp.asarray(key_arr),
-            value=jax.tree_util.tree_unflatten(
-                treedef, [jnp.asarray(v) for v in val_leaves]
-            ),
-            ts=jnp.asarray(ts),
-            off=jnp.asarray(off),
-            valid=jnp.asarray(valid),
+        events = self._event_batch(
+            key_arr, treedef, val_leaves, ts, off, valid
         )
         return events, rank_of, len(records) - dropped
 
@@ -986,16 +986,25 @@ class CEPProcessor:
             (col_start, qlen.astype(np.int64), abs_ts, val_leaves)
         )
 
-        events = EventBatch(
-            key=jnp.asarray(key_arr),
-            value=jax.tree_util.tree_unflatten(
-                treedef, [jnp.asarray(v) for v in val_leaves]
-            ),
-            ts=jnp.asarray(ts),
-            off=jnp.asarray(off),
-            valid=jnp.asarray(valid),
+        events = self._event_batch(
+            key_arr, treedef, val_leaves, ts, off, valid
         )
         return events, rank_of, n
+
+    def _event_batch(self, key, treedef, val_leaves, ts, off, valid):
+        """The packed ``[K, T]`` host columns as the scan's ``EventBatch``:
+        on the device here, or, on a mesh, left on the host for the
+        dispatch to place each lane block straight onto its device."""
+        put = np.asarray if self.mesh is not None else jnp.asarray
+        return EventBatch(
+            key=put(key),
+            value=jax.tree_util.tree_unflatten(
+                treedef, [put(v) for v in val_leaves]
+            ),
+            ts=put(ts),
+            off=put(off),
+            valid=put(valid),
+        )
 
     # -- columnar lane resolution ------------------------------------------
 
@@ -1138,12 +1147,16 @@ class CEPProcessor:
             # so the supervisor's evacuation can restore-and-replay onto
             # the surviving sub-mesh (arm with ShardLost to drive it).
             _failpoint("shard.dispatch")
-            events = self.batch.shard_events(events)
 
         base = self._step_base
         if lat is not None:
             lat.dispatch = self._clock()
         with self._phase("dispatch"):
+            if self.mesh is not None:
+                with self._phase("shard_put"):
+                    # Host columns to each device's lane block, in one
+                    # transfer.
+                    events = self.batch.shard_events(events)
             # Enqueue only: the scan (and any due sweep) dispatch async;
             # the wait is attributed to the device phase below.
             self.state, out = self.batch.scan(self.state, events)
@@ -1368,7 +1381,12 @@ class CEPProcessor:
         full pull (counted in ``decode_fallbacks``; correctness never
         depends on the budget).
         """
-        if self.decode_budget:
+        if self.decode_budget and self.mesh is not None:
+            matches = self._decode_sharded(out, rank_of)
+            if matches is not None:
+                return matches
+            self.metrics.decode_fallbacks += 1
+        elif self.decode_budget:
             from kafkastreams_cep_tpu.ops.decode import compact_matches
 
             K, T, R = out.count.shape
@@ -1409,6 +1427,36 @@ class CEPProcessor:
         return self._emit(
             ks, ts, rs, count[ks, ts, rs], stage[ks, ts, rs],
             off[ks, ts, rs], rank_of,
+        )
+
+    def _decode_sharded(self, out, rank_of):
+        """The eager decode on a mesh: each shard compacts its own lanes'
+        hit rows into its share of ``decode_budget``
+        (``ShardedMatcher.compact_matches``, no exchange between chips);
+        one transfer brings every shard's compacted rows to the host, and
+        the hit rows, in shard order, are the global (k, t, r) order.
+        ``None`` when a shard's hits overflow its share (the caller pulls
+        the full grid).  Counts each call's largest and smallest shard
+        match count in ``shard_matches_max`` / ``shard_matches_min``."""
+        n_sh = int(self.mesh.devices.size)
+        budget = max(self.decode_budget // n_sh, 1)
+        with self._phase("decode_wait"):
+            rows = self.batch.compact_matches(out, budget)
+            hits = np.asarray(jax.device_get(rows[-1]))  # [n_sh]
+        self.metrics.shard_matches_max += int(hits.max())
+        self.metrics.shard_matches_min += int(hits.min())
+        G = int(rows[2].shape[0]) // n_sh
+        if (hits > G).any():
+            return None
+        if not hits.any():
+            return []
+        stage, off, count, k, t, r = jax.device_get(rows[:6])
+        take = np.concatenate(
+            [np.arange(s * G, s * G + h) for s, h in enumerate(hits)]
+        )
+        return self._emit(
+            k[take], t[take], r[take], count[take], stage[take], off[take],
+            rank_of,
         )
 
     def _emit(self, ks, ts, rs, cnts, stages, offs, rank_of):

@@ -37,6 +37,10 @@ from kafkastreams_cep_tpu.utils.telemetry import (
 #: (both summed over GC passes).  ``pack_lane_misses`` counts the
 #: columnar rows whose key the pack's sorted key index did not hold (new
 #: keys, and every row of a column the index does not serve).
+#: ``shard_matches_max`` / ``shard_matches_min`` sum, over a meshed
+#: processor's calls, the largest and the smallest number of matches one
+#: shard compacted in the call (the skew of matches over the chips; 0
+#: unmeshed).
 COUNTER_ATTRS = (
     "records_in",
     "matches_out",
@@ -49,6 +53,8 @@ COUNTER_ATTRS = (
     "gc_events_materialized",
     "gc_lanes_swept",
     "pack_lane_misses",
+    "shard_matches_max",
+    "shard_matches_min",
 )
 
 #: Wall-time accumulators; each also feeds the phase histogram of the same
@@ -57,8 +63,9 @@ COUNTER_ATTRS = (
 #: for the match compaction) and ``decode_build`` (Sequence/Event
 #: construction) inside ``decode``, ``gc_pull`` (the liveness transfer)
 #: inside ``gc``, ``pack_lanes`` (the key-to-lane lookup of a columnar
-#: call) inside ``pack``; a parent's self time is its seconds minus its
-#: children's.
+#: call) inside ``pack``, ``shard_put`` (a meshed call's host-to-mesh
+#: event transfer) inside ``dispatch``; a parent's self time is its
+#: seconds minus its children's.
 SECONDS_ATTRS = (
     "device_seconds",
     "decode_seconds",
@@ -70,13 +77,15 @@ SECONDS_ATTRS = (
     "decode_build_seconds",
     "gc_pull_seconds",
     "pack_lanes_seconds",
+    "shard_put_seconds",
 )
 
 #: The batch phases every processor pre-registers, so snapshots of runs
 #: that never hit a phase (e.g. gc off, eager extraction) still carry
 #: identical key sets.
 PHASE_NAMES = ("pack", "dispatch", "drain", "device", "decode", "gc",
-               "decode_wait", "decode_build", "gc_pull", "pack_lanes")
+               "decode_wait", "decode_build", "gc_pull", "pack_lanes",
+               "shard_put")
 
 
 def _counter_property(name: str) -> property:

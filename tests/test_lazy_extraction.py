@@ -291,8 +291,9 @@ def test_sweep_preserves_pinned_handles():
     lazy = BatchMatcher(stock_demo.stock_pattern(), K, LAZY)
     st, _ = lazy.scan(lazy.init_state(), events)
     assert int(jnp.sum(st.hr_count)) > 0
-    _, want = lazy.drain(st)  # reference drain, no sweep
-    swept = lazy.sweep(st)  # sweep WITH pending handles
+    st = jax.device_get(st)  # host: the drain and the sweep each consume
+    _, want = lazy.drain(jax.device_put(st))  # reference drain, no sweep
+    swept = lazy.sweep(jax.device_put(st))  # sweep WITH pending handles
     _, got = lazy.drain(swept)
     assert drained_matches(want) == drained_matches(got)
 

@@ -114,13 +114,14 @@ def test_widening_is_pure_embedding(dim, seed):
 
     narrow = BatchMatcher(stock_demo.stock_pattern(), K, NARROW)
     mid, _ = narrow.scan(narrow.init_state(), prefix)
+    mid_h = jax.device_get(mid)  # the next scan consumes ``mid``
     st_n, out_n = narrow.scan(mid, suffix)
     assert not any(capacity_counters(narrow.counters(st_n)).values()), (
         "precondition: the narrow run must be loss-free for bit-exactness"
     )
 
     wide = BatchMatcher(stock_demo.stock_pattern(), K, wide_cfg)
-    mid_w = jax.device_put(widen_state(mid, NARROW, wide_cfg))
+    mid_w = jax.device_put(widen_state(mid_h, NARROW, wide_cfg))
     st_w, out_w = wide.scan(mid_w, suffix)
 
     R = NARROW.max_runs
@@ -157,14 +158,14 @@ def test_kernel_and_jnp_paths_agree_on_migrated_state():
     os.environ["CEP_WALK_KERNEL"] = "0"
     narrow = BatchMatcher(stock_demo.stock_pattern(), K, NARROW)
     mid, _ = narrow.scan(narrow.init_state(), prefix)
-    mid_w = jax.device_put(widen_state(mid, NARROW, wide_cfg))
+    mid_w = widen_state(mid, NARROW, wide_cfg)  # host: each scan consumes
     wide_ref = BatchMatcher(stock_demo.stock_pattern(), K, wide_cfg)
-    st_r, out_r = wide_ref.scan(mid_w, suffix)
+    st_r, out_r = wide_ref.scan(jax.device_put(mid_w), suffix)
     os.environ["CEP_WALK_KERNEL"] = "interpret"
     try:
         wide_krn = BatchMatcher(stock_demo.stock_pattern(), K, wide_cfg)
         assert wide_krn.uses_walk_kernel
-        st_k, out_k = wide_krn.scan(mid_w, suffix)
+        st_k, out_k = wide_krn.scan(jax.device_put(mid_w), suffix)
     finally:
         os.environ["CEP_WALK_KERNEL"] = "0"
     for f in ("count", "stage", "off"):
@@ -187,11 +188,12 @@ def test_two_tier_slab_widens_with_hot_window_intact():
     suffix = stock_events(K, T, 111, t0=T)
     a = BatchMatcher(stock_demo.stock_pattern(), K, narrow)
     mid, _ = a.scan(a.init_state(), prefix)
+    mid_h = jax.device_get(mid)  # the next scan consumes ``mid``
     st_n, out_n = a.scan(mid, suffix)
     assert not any(capacity_counters(a.counters(st_n)).values())
     b = BatchMatcher(stock_demo.stock_pattern(), K, wide_cfg)
     st_w, out_w = b.scan(
-        jax.device_put(widen_state(mid, narrow, wide_cfg)), suffix
+        jax.device_put(widen_state(mid_h, narrow, wide_cfg)), suffix
     )
     np.testing.assert_array_equal(
         np.asarray(out_n.count), np.asarray(out_w.count)
@@ -221,13 +223,14 @@ def test_handle_ring_widens_with_pending_handles():
     narrow = BatchMatcher(stock_demo.stock_pattern(), K, lazy_narrow)
     mid, _ = narrow.scan(narrow.init_state(), prefix)  # NOT drained
     assert int(jnp.sum(mid.hr_count)) > 0
+    mid_h = jax.device_get(mid)  # the next scan consumes ``mid``
     st_n, _ = narrow.scan(mid, suffix)
     st_n, d_n = narrow.drain(st_n)
     assert not any(capacity_counters(narrow.counters(st_n)).values())
     for name, w in widenings.items():
         wide_cfg = dataclasses.replace(lazy_narrow, **w)
         wide = BatchMatcher(stock_demo.stock_pattern(), K, wide_cfg)
-        mid_w = jax.device_put(widen_state(mid, lazy_narrow, wide_cfg))
+        mid_w = jax.device_put(widen_state(mid_h, lazy_narrow, wide_cfg))
         st_w, _ = wide.scan(mid_w, suffix)
         st_w, d_w = wide.drain(st_w)
         HB, W0 = lazy_narrow.handle_ring, lazy_narrow.max_walk

@@ -64,9 +64,10 @@ def _scan_permute_scan(cfg, K=8, T=10, drain=False):
     suffix = stock_events(K, T, seed=131, t0=T)
     m = BatchMatcher(stock_demo.stock_pattern(), K, cfg)
     mid, _ = m.scan(m.init_state(), prefix)
+    # Permuted before the scan below consumes ``mid``.
+    mid_p = jax.device_put(repartition_state(mid, PERM))
     st_a, out_a = m.scan(mid, suffix)
 
-    mid_p = jax.device_put(repartition_state(mid, PERM))
     suffix_p = jax.device_put(repartition_state(suffix, PERM))
     st_b, out_b = m.scan(mid_p, suffix_p)
 

@@ -158,3 +158,47 @@ def test_tiered_scan_fits_one_chip_at_the_prefix_cell(one_chip, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16 * 2**30
+
+
+def test_sharded_stock_fits_a_chip_at_the_mesh_cell(topo, monkeypatch):
+    """The sharded programs of ``stock-131072x4`` at the cell's own shapes
+    (131,072 lanes a chip of a ``v5e:2x2``, calls of 8 steps, the
+    configuration's engine).  The scan takes the state donated and the walk
+    kernel updates the slab in place: arguments + outputs + temporaries −
+    aliased were 5,485,625,344 + 8,319,926,784 + 4,090,195,456 −
+    5,467,799,552 B (12.43e9) a chip when this case was written, against
+    24.5e9 with no donation and a relaid slab.  The sweep, donated and run
+    by lane blocks, 5,467,799,552 + 5,467,800,064 + 3,036,619,776 −
+    5,467,799,552 B (8.50e9), runs while the call's match grid is held
+    for the decode.  Each stays 1 GiB under the chip's 16 GiB."""
+    from harness import spec
+
+    monkeypatch.setenv("CEP_WALK_KERNEL", "1")
+    conf = spec.load_json(os.path.join(
+        os.path.dirname(__file__), "..", "benchmark", "configs",
+        "stock-131072x4.json"))
+    lanes, steps, chips = int(conf["lanes"]), 8, 4
+    assert lanes // chips == 131072
+    mesh = Mesh(topo.devices[:chips], ("keys",))
+    sharded = ShardedMatcher(spec.build_query(conf["pattern"]), lanes, mesh,
+                             spec.engine_config(conf))
+    assert sharded.uses_walk_kernel
+    spread = NamedSharding(mesh, PartitionSpec("keys"))
+    state = _state(sharded.matcher, lanes, spread)
+    limit = 15 * 2**30
+
+    def used(compiled):
+        mem = compiled.memory_analysis()
+        return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+    scan = sharded.scan.lower(state, _events(lanes, steps, spread)).compile()
+    text = scan.as_text()
+    assert "HloModule jit_sharded_scan" in text
+    assert "tpu_custom_call" in text
+    assert used(scan) < limit
+    cfg = sharded.matcher.config
+    grid = (lanes // chips) * steps * cfg.max_runs * (2 * cfg.max_walk + 1) * 4
+    sweep = sharded._sweep_jit.lower(state).compile()
+    assert "HloModule jit_sharded_sweep" in sweep.as_text()
+    assert used(sweep) + grid < limit

@@ -234,7 +234,7 @@ def test_kernel_put_phase_matches_sequential():
 # ---------------------------------------------------------------------------
 
 
-def _x_events(xs, ts_mult=1):
+def _x_events(xs, ts_mult=1, valid=None):
     K_, T = xs.shape
     steps = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (K_, T))
     return EventBatch(
@@ -244,7 +244,7 @@ def _x_events(xs, ts_mult=1):
         value={"x": jnp.asarray(xs)},
         ts=steps * ts_mult,
         off=steps,
-        valid=jnp.ones((K_, T), bool),
+        valid=jnp.ones((K_, T), bool) if valid is None else valid,
     )
 
 
@@ -291,26 +291,35 @@ _SMALL = dict(
 
 
 @pytest.mark.parametrize(
-    "pattern,extra,seed,high,ts_mult",
+    "pattern,extra,seed,high,ts_mult,padded",
     [
-        (_typed_float_folds, {}, 11, 6, 1),
-        (_windowed_pair, {"enforce_windows": True}, 13, 4, 3),
-        (_strict_chain, {}, 17, 5, 1),
+        (_typed_float_folds, {}, 11, 6, 1, False),
+        (_windowed_pair, {"enforce_windows": True}, 13, 4, 3, False),
+        (_strict_chain, {}, 17, 5, 1, False),
+        (_typed_float_folds, {}, 19, 6, 1, True),
     ],
-    ids=["typed-float-folds", "enforced-5ms-window", "strict-3-chain"],
+    ids=["typed-float-folds", "enforced-5ms-window", "strict-3-chain",
+         "padded-lanes"],
 )
 def test_walk_kernel_matches_jnp_batch(
-    monkeypatch, pattern, extra, seed, high, ts_mult
+    monkeypatch, pattern, extra, seed, high, ts_mult, padded
 ):
     """``BatchMatcher`` with the walk pass on the kernel (interpret mode)
     against the all-jnp engine: every ``StepOutput`` field and every
     engine-state leaf bit-equal after one ``[K, T]`` scan.  States are
     compared through ``canonical_state``: slots the engine never reads
-    hold implementation-dependent residue."""
+    hold implementation-dependent residue.  ``padded``: each lane's
+    events end at its own step, the rest padding, as a packed call of
+    uneven keys lays them (the kernel must leave a padding step's slab as
+    it was)."""
     cfg = EngineConfig(**_SMALL, **extra)
     rng = np.random.default_rng(seed)
     xs = rng.integers(0, high, size=(LANE_BLOCK, 14)).astype(np.int32)
-    events = _x_events(xs, ts_mult)
+    valid = None
+    if padded:
+        ends = rng.integers(4, 15, size=(LANE_BLOCK, 1))
+        valid = jnp.asarray(np.arange(14)[None, :] < ends)
+    events = _x_events(xs, ts_mult, valid)
     runs = {}
     for mode in ("0", "interpret"):
         monkeypatch.setenv("CEP_WALK_KERNEL", mode)
